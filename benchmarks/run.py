@@ -26,6 +26,8 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     filters = [a for a in sys.argv[1:] if not a.startswith("-")]
     print("name,us_per_call,derived")
     failures = 0

@@ -8,16 +8,12 @@ too — the ratchet only moves forward, forcing a baseline prune commit —
 while `--update` rewrites the baseline to the current failure set
 (pruning fixed tests, recording triaged new ones).
 
-The baseline is keyed by jax major.minor so each CI matrix leg (oldest
-pin vs latest) carries its own failure set; a missing key means "no
-known failures" for that leg. The special `_min_collected` key maps
-each jax series to its collected-test floor (per leg, like the
-failure sets — import guards can legitimately collect different
-counts per jax): the gate fails when fewer tests are collected than
-that leg's floor, so a whole test file silently dropping out of
-collection (an import-guard skip, a renamed module) is a gated
-regression too — new suites join the ratchet by re-recording the
-floor with --update.
+The baseline holds the known failure set (`failures`) and the
+collected-test floor (`min_collected`): the gate fails when fewer tests
+are collected than the floor, so a whole test file silently dropping
+out of collection (an import-guard skip, a renamed module) is a gated
+regression too — new suites join the ratchet by re-recording the floor
+with --update.
 
   python scripts/check_regressions.py                 # gate (CI)
   python scripts/check_regressions.py --update        # re-record
@@ -37,11 +33,6 @@ from _ratchet import diff_ratchet, dump_json, load_json  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_BASELINE = os.path.join(REPO, "tests", "known_failures.json")
-
-
-def jax_series() -> str:
-    import jax
-    return ".".join(jax.__version__.split(".")[:2])
 
 
 def run_pytest(extra: list) -> tuple:
@@ -83,40 +74,35 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--baseline", default=DEFAULT_BASELINE)
     ap.add_argument("--update", action="store_true",
-                    help="rewrite this jax series' baseline to the "
-                         "current failure set")
+                    help="rewrite the baseline to the current "
+                         "failure set")
     ap.add_argument("--allow-stale", action="store_true",
                     help="fixed baseline entries warn instead of fail")
     ap.add_argument("pytest_args", nargs="*",
                     help="extra args forwarded to pytest (after --)")
     args = ap.parse_args()
 
-    series = jax_series()
     failed, total = run_pytest(args.pytest_args)
-    baseline_all = load_json(args.baseline, default={})
-    known = set(baseline_all.get(series, baseline_all.get("default", [])))
+    baseline = load_json(args.baseline, default={})
+    known = set(baseline.get("failures", []))
     # the collected floor only means anything for a full-suite run:
     # forwarded pytest args select a subset, which must neither trip
     # the shrink gate nor re-record a tiny floor
     full_suite = not args.pytest_args
-    floors = baseline_all.get("_min_collected", {})
-    floor = int(floors.get(series, min(floors.values(), default=0))) \
-        if full_suite else 0
+    floor = int(baseline.get("min_collected", 0)) if full_suite else 0
 
     new, stale = diff_ratchet(failed, known)
-    print(f"\n[check_regressions] jax {series}: {total} tests, "
+    print(f"\n[check_regressions] {total} tests, "
           f"{len(failed)} failed ({len(known)} known, "
           f"collected floor {floor})")
 
     if args.update:
-        baseline_all[series] = sorted(failed)
-        if not baseline_all[series]:
-            baseline_all.pop(series)
+        baseline["failures"] = sorted(failed)
         if full_suite:
-            baseline_all.setdefault("_min_collected", {})[series] = total
-        dump_json(args.baseline, baseline_all)
-        print(f"[check_regressions] baseline[{series}] <- "
-              f"{len(failed)} entries, _min_collected <- {total} "
+            baseline["min_collected"] = total
+        dump_json(args.baseline, baseline)
+        print(f"[check_regressions] baseline <- "
+              f"{len(failed)} entries, min_collected <- {total} "
               f"({args.baseline})")
         return 0
 

@@ -77,10 +77,10 @@ class PlainRef:
     value = property(lambda self: self._a)
 
     def __getitem__(self, ix):
-        return self._a[ix]
+        return self._a[_np_index(ix)]
 
     def __setitem__(self, ix, val):
-        self._a[ix] = np.asarray(val)
+        self._a[_np_index(ix)] = np.asarray(val)
 
     def __jax_array__(self):          # jnp.zeros_like(y_ref) etc.
         import jax.numpy as jnp
@@ -88,20 +88,28 @@ class PlainRef:
 
 
 class _DS:
-    """Shadow pl.ds: a (start, size) row window."""
+    """Shadow pl.ds: a (start, size) window."""
 
     def __init__(self, start, size):
         self.start, self.size = int(start), int(size)
 
 
+def _np_index(ix):
+    """A pallas-style index (pl.ds windows, slices, ints) as a numpy
+    index."""
+    if isinstance(ix, _DS):
+        return slice(ix.start, ix.start + ix.size)
+    if isinstance(ix, tuple):
+        return tuple(_np_index(i) for i in ix)
+    return ix
+
+
 class _SrcSlice:
-    def __init__(self, arr, ds):
-        self._arr, self._ds = arr, ds
+    def __init__(self, arr, ix):
+        self._arr, self._ix = arr, ix
 
     def read(self):
-        if self._ds is None:
-            return self._arr.copy()
-        return self._arr[self._ds.start:self._ds.start + self._ds.size].copy()
+        return self._arr[_np_index(self._ix)].copy()
 
 
 class HBMRef:
@@ -123,7 +131,7 @@ class _HBMAt:
         self._arr = arr
 
     def __getitem__(self, ix):
-        return _SrcSlice(self._arr, ix if isinstance(ix, _DS) else None)
+        return _SrcSlice(self._arr, ix)
 
 
 def _slot_of(ix):
@@ -282,6 +290,10 @@ class _ShadowPl:
     def ds(start, size):
         return _DS(start, size)
 
+    @staticmethod
+    def multiple_of(x, _m):
+        return x
+
     def run_scoped(self, body, **kwargs):
         allocs = {}
         for name, spec in kwargs.items():
@@ -384,8 +396,9 @@ def run_fused_shadow(x, wc, A, Bp, *, activation: str, kc: int,
     san = Sanitizer(case)
     y_ref = PlainRef(np.zeros((B, D), np.float32))
     idx_ref = PlainRef(np.zeros((G, kc), np.int32))
-    w_hbm = HBMRef(w_flat)
-    wout_hbm = None if wout_flat is None else HBMRef(wout_flat)
+    w_hbm = HBMRef(cg._kernel_layout(w_flat))
+    wout_hbm = None if wout_flat is None else HBMRef(
+        cg._kernel_layout(wout_flat, np.float32))
     with shadow_env(cg, san):
         for g in range(G):
             san.program_id = g

@@ -50,7 +50,7 @@ JAXPR_RULES = ("jaxpr-collective-count", "jaxpr-collective-fp32",
 # so psum_scatter never counts as the output reduction)
 _PSUM = {"psum", "psum2", "psum_invariant"}
 _ALL_GATHER = {"all_gather", "all_gather_invariant"}
-_CALLBACK = {"pure_callback", "io_callback", "debug_callback"}
+_CALLBACK = {"pure_callback", "io_callback", "debug_callback", "debug_print"}
 
 
 def _subjaxprs(val):

@@ -3,8 +3,6 @@ collective-fp32. Fixture only — never imported or executed."""
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-
 
 def local(x):
     a = jax.lax.psum(x.astype(jnp.float32), "model")
@@ -13,8 +11,8 @@ def local(x):
 
 
 def build(mesh):
-    return shard_map(local, mesh=mesh, in_specs=("model",),
-                     out_specs=("model",), axis_names={"model"})
+    return jax.shard_map(local, mesh=mesh, in_specs=("model",),
+                         out_specs=("model",), axis_names={"model"})
 
 
 def stray(x):

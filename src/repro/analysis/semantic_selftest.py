@@ -46,13 +46,12 @@ __all__ = ["fixture_entries", "clean_entries", "MUTANTS", "CLEAN_MINI",
 def _shard_mapped(local):
     """Wrap a shard-local body over the ambient 'model' mesh axis."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.sharding import current_mesh
 
     def fn(x):
-        return shard_map(local, mesh=current_mesh(),
-                         in_specs=(P("model"),), out_specs=P(None),
-                         axis_names={"model"}, check_vma=False)(x)
+        return jax.shard_map(local, mesh=current_mesh(),
+                             in_specs=(P("model"),), out_specs=P(None),
+                             axis_names={"model"}, check_vma=False)(x)
     return fn, (jnp.zeros((8,), jnp.float32),)
 
 
@@ -87,8 +86,7 @@ def _build_f64():
 
 
 def _x64_ctx():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def _build_callback():

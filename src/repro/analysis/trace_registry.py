@@ -60,12 +60,11 @@ class TraceEntry:
 
     def trace(self):
         """Stage to a ClosedJaxpr under the declared mesh."""
-        from repro.compat import set_mesh
         from repro.launch.mesh import make_serving_mesh
         fn, args = self.build()
         mesh = (make_serving_mesh(self.n_devices)
                 if self.n_devices > 1 else None)
-        mesh_ctx = (set_mesh(mesh) if mesh is not None
+        mesh_ctx = (jax.set_mesh(mesh) if mesh is not None
                     else contextlib.nullcontext())
         extra = self.trace_ctx() if self.trace_ctx else \
             contextlib.nullcontext()

@@ -87,10 +87,8 @@ class BucketedDecoder:
 
     @staticmethod
     def _bind_mesh(fn, mesh):
-        from repro.compat import set_mesh
-
         def call(*args, **kwargs):
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return fn(*args, **kwargs)
         return call
 

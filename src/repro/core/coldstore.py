@@ -11,9 +11,10 @@ the paper's two I/O refinements:
   * block-size-aware reads: bundle fetches are split into the block
     size that maximizes the storage model's bandwidth.
 
-On a pod the "flash" is host DRAM: fetch() returns real numpy rows and
-a *modeled* I/O time from the configured StorageModel, so the serving
-engine and the pipeline benchmarks get both data and timing.
+The store prices I/O only: it keeps each layer's bundle shape and byte
+count, never the weights themselves (those live on the device), and
+fetch() returns the bytes moved and the *modeled* I/O time from the
+configured StorageModel.
 """
 from __future__ import annotations
 
@@ -26,26 +27,39 @@ from repro.core.io_model import StorageModel, UFS40
 
 @dataclass
 class FetchResult:
-    rows: np.ndarray          # (k, R, D) bundle rows
     nbytes: int
     io_time: float            # modeled seconds
     n_ops: int
 
 
-class ColdStore:
-    """Per-layer bundled neuron store backed by host memory."""
+@dataclass(frozen=True)
+class BundleLayout:
+    """One layer's bundled (N, R, D) tensor, as the store prices it."""
+    n: int                    # neurons (bundles)
+    rows: int                 # R: Gate/Up/Down rows per bundle
+    bundle_bytes: int         # bytes of one (R, D) bundle
 
-    def __init__(self, bundles_per_layer, storage: StorageModel = UFS40,
+    @classmethod
+    def of(cls, shape, dtype) -> "BundleLayout":
+        """From an (N, R, D) shape and dtype — no array data read."""
+        n, rows, d = shape
+        return cls(int(n), int(rows), int(rows * d * np.dtype(dtype).itemsize))
+
+
+class ColdStore:
+    """Per-layer bundled neuron store: bundle shapes and byte counts."""
+
+    def __init__(self, layouts, storage: StorageModel = UFS40,
                  two_phase: bool = False, block_size: int = 24576,
                  bundle_bytes_override: int = None,
                  count_scale: float = 1.0):
-        """bundles_per_layer: list of np arrays (N, R, D) — one per layer,
-        already permuted hot-first by the planner.
+        """layouts: one `BundleLayout` per layer (the hot-first permuted
+        (N, R, D) bundle tensor's shape and per-bundle bytes).
 
         bundle_bytes_override / count_scale let a reduced model's store
         price I/O at deployment-size constants (serving.TimingProfile).
         """
-        self.layers = [np.asarray(b) for b in bundles_per_layer]
+        self.layers = list(layouts)
         self.storage = storage
         self.two_phase = two_phase
         self.block_size = block_size
@@ -58,8 +72,7 @@ class ColdStore:
     def bundle_bytes(self, layer: int = 0) -> int:
         if self.bundle_bytes_override:
             return int(self.bundle_bytes_override)
-        b = self.layers[layer]
-        return int(b[0].nbytes)
+        return int(self.layers[layer].bundle_bytes)
 
     def fetch(self, layer: int, neuron_ids, gate_active=None) -> FetchResult:
         """Random-read the given neuron bundles.
@@ -68,13 +81,12 @@ class ColdStore:
         inactive gates skip the Up/Down half of the bundle.
         """
         ids = np.asarray(neuron_ids, dtype=np.int64)
-        rows = self.layers[layer][ids]
         per_bundle = self.bundle_bytes(layer)
         n_eff = len(ids) * self.count_scale
         if self.two_phase and gate_active is not None:
             act = np.asarray(gate_active, dtype=bool)
             # gate = 1/R of the bundle; up/down only when active
-            R = rows.shape[1]
+            R = self.layers[layer].rows
             nbytes = int(per_bundle / R * n_eff
                          + per_bundle * (R - 1) / R * act.sum()
                          * self.count_scale)
@@ -87,16 +99,7 @@ class ColdStore:
         self.total_fetches += n_ops
         self.total_bytes += nbytes
         self.total_io_time += t
-        return FetchResult(rows=rows, nbytes=nbytes, io_time=t, n_ops=n_ops)
-
-    def fetch_sequential(self, layer: int) -> FetchResult:
-        """Stream a whole layer (prefill / hot-region preload, §4.1.1)."""
-        rows = self.layers[layer]
-        nbytes = int(rows.nbytes)
-        t = self.storage.read_time(nbytes, 524288, random=False)
-        self.total_bytes += nbytes
-        self.total_io_time += t
-        return FetchResult(rows=rows, nbytes=nbytes, io_time=t, n_ops=1)
+        return FetchResult(nbytes=nbytes, io_time=t, n_ops=n_ops)
 
     def reset_stats(self):
         self.total_fetches = 0

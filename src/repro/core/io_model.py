@@ -109,7 +109,7 @@ class KernelCalibration:
     dense_flops_per_s: float       # measured dense (hot-prefix) engine
     sparse_flops_per_s: float      # measured fused gathered cold path
     gather_bytes_per_s: float      # weight bytes/s the cold path moved
-    source: str = "uncalibrated"   # e.g. "interpret-cpu jax 0.4.37"
+    source: str = "uncalibrated"   # e.g. "interpret-cpu jax 0.9.0"
 
     @staticmethod
     def from_rows(rows) -> "KernelCalibration":

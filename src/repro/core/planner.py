@@ -339,19 +339,17 @@ def hot_io_cap(cfg: ModelConfig, hw: HardwareProfile,
 def permute_ffn_params(params, order: np.ndarray):
     """Reorder each layer's FFN bundle rows (and predictor columns)
     hot-first, matching the plan. params['layers']['ffn'] leaves are
-    stacked (L, ...)."""
-    def permute_layer(w, ord_l):
-        return w[ord_l]
-
+    stacked (L, ...). The gather runs where the weights live."""
     layers = params["layers"]
     ffn = layers["ffn"]
-    w = np.asarray(ffn["w"])                                # (L, N, R, D)
-    w = np.stack([w[l][order[l]] for l in range(w.shape[0])])
-    new_ffn = dict(ffn, w=jnp.asarray(w))
+    idx = jnp.asarray(order)                                # (L, N)
+    w = jnp.take_along_axis(ffn["w"], idx[:, :, None, None],
+                            axis=1)                         # (L, N, R, D)
+    new_ffn = dict(ffn, w=w)
     if "pred" in ffn:
-        Bm = np.asarray(ffn["pred"]["B"])                   # (L, r, N)
-        Bm = np.stack([Bm[l][:, order[l]] for l in range(Bm.shape[0])])
-        new_ffn["pred"] = dict(ffn["pred"], B=jnp.asarray(Bm))
+        Bm = jnp.take_along_axis(ffn["pred"]["B"], idx[:, None, :],
+                                 axis=2)                    # (L, r, N)
+        new_ffn["pred"] = dict(ffn["pred"], B=Bm)
     new_layers = dict(layers, ffn=new_ffn)
     return dict(params, layers=new_layers)
 

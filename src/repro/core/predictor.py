@@ -33,11 +33,20 @@ def predictor_spec():
     return {"A": P(None, None), "B": P(None, "model")}
 
 
+# Scores decide which clusters run, so they are computed at full fp32
+# precision on every backend: the chip's default fp32 matmul rounds
+# its inputs to bf16, which would let the jnp and pallas cold paths
+# pick different clusters from the same hidden state.
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def predict_scores(params, x):
     """x (..., d_model) -> neuron scores (..., n_neurons), fp32."""
     h = jnp.einsum("...d,dr->...r", x.astype(jnp.float32),
-                   params["A"].astype(jnp.float32))
-    s = jnp.einsum("...r,rn->...n", h, params["B"].astype(jnp.float32))
+                   params["A"].astype(jnp.float32),
+                   precision=SCORE_PRECISION)
+    s = jnp.einsum("...r,rn->...n", h, params["B"].astype(jnp.float32),
+                   precision=SCORE_PRECISION)
     return constrain(s, P(None, "model")) if s.ndim == 2 else s
 
 

@@ -37,7 +37,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.clusters import HybridPlan
-from repro.core.predictor import init_predictor, predictor_spec, predict_scores
+from repro.core.predictor import (
+    SCORE_PRECISION, init_predictor, predictor_spec, predict_scores)
 from repro.models.modules import dense_init, activation_fn
 from repro.sharding import constrain, BATCH
 
@@ -151,7 +152,6 @@ def _cold_path_shard_map(params, x, activation: str, mode: str,
     scoring (free KV-arena slots decode garbage lanes; they must not
     steer cluster selection for live requests)."""
     from jax.sharding import PartitionSpec as PS
-    from repro.compat import shard_map
     from repro.sharding import current_mesh
 
     mesh = current_mesh()
@@ -194,8 +194,9 @@ def _cold_path_shard_map(params, x, activation: str, mode: str,
             return (jax.lax.psum(y.astype(jnp.float32), "model"),
                     jax.lax.all_gather(idx, "model").reshape(G, kc))
         h = jnp.einsum("bd,dr->br", xl.astype(jnp.float32),
-                       Al.astype(jnp.float32))
-        scores = jnp.einsum("br,rn->bn", h, Bl.astype(jnp.float32))
+                       Al.astype(jnp.float32), precision=SCORE_PRECISION)
+        scores = jnp.einsum("br,rn->bn", h, Bl.astype(jnp.float32),
+                            precision=SCORE_PRECISION)
         union = jnp.where(maskl[:, None], scores,
                           -jnp.inf).max(axis=0)       # (Nc_local,)
         cscore = union.reshape(g_loc * nc_g, cs).max(axis=-1)
@@ -210,9 +211,11 @@ def _cold_path_shard_map(params, x, activation: str, mode: str,
                 wcl.reshape(g_loc, nc_g, cs, R, D),
                 idx[:, :, None, None, None], axis=1)  # (g_loc,kc,cs,R,D)
         gath = gath.reshape(g_loc * kc * cs, R, D)
-        g = jnp.einsum("bd,kd->bk", xl, gath[:, 0])
+        g = jnp.einsum("bd,kd->bk", xl, gath[:, 0],
+                       preferred_element_type=jnp.float32)
         if R == 3:
-            u = jnp.einsum("bd,kd->bk", xl, gath[:, 1])
+            u = jnp.einsum("bd,kd->bk", xl, gath[:, 1],
+                           preferred_element_type=jnp.float32)
             hh = act(g) * u
         else:
             hh = act(g)
@@ -220,7 +223,8 @@ def _cold_path_shard_map(params, x, activation: str, mode: str,
             tok = scores.reshape(-1, g_loc, nc_g, cs)
             tok = jnp.take_along_axis(tok, idx[None, :, :, None], axis=2)
             hh = hh * (tok.reshape(hh.shape) > 0.0).astype(hh.dtype)
-        y = jnp.einsum("bk,kd->bd", hh.astype(w.dtype), gath[:, -1])
+        y = jnp.einsum("bk,kd->bd", hh.astype(w.dtype), gath[:, -1],
+                       preferred_element_type=jnp.float32)
         # psum in f32: XLA:CPU's AllReducePromotion pass crashes on
         # bf16 all-reduce inside partial-manual shard_map (and f32
         # reduction is numerically better anyway).
@@ -242,7 +246,7 @@ def _cold_path_shard_map(params, x, activation: str, mode: str,
             operands.append(
                 params["wout"][n_hot:].reshape(G * nc_g, cs, R, D))
             in_specs.append(PS("model", None, None, None))
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(PS(None, None), PS(None, None)),
@@ -336,9 +340,14 @@ def ffn_hybrid(params, x, activation: str, mode: str, plan: HybridPlan,
                 wc, cidx[:, :, None, None, None], axis=1)  # (G,kc,cs,R,D)
         gath = gath.reshape(G, kc * cs, R, D)
         act = activation_fn(activation)
-        g = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 0])
+        # fp32 gate/up activations, bf16 operands for the down
+        # projection: the fused kernel's numerics, so the backends
+        # differ only in accumulation order
+        g = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 0],
+                       preferred_element_type=jnp.float32)
         if R == 3:
-            u = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 1])
+            u = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 1],
+                           preferred_element_type=jnp.float32)
             h = act(g) * u
         else:
             h = act(g)
@@ -349,7 +358,9 @@ def ffn_hybrid(params, x, activation: str, mode: str, plan: HybridPlan,
             tok = jnp.take_along_axis(
                 tok, cidx[None, :, :, None], axis=2)    # (B,G,kc,cs)
             h = h * (tok.reshape(B, G, kc * cs) > 0.0).astype(h.dtype)
-        y_cold = jnp.einsum("bgk,gkd->bd", h.astype(w.dtype), gath[:, :, -1])
+        y_cold = jnp.einsum("bgk,gkd->bd", h.astype(w.dtype),
+                            gath[:, :, -1],
+                            preferred_element_type=jnp.float32)
         y += y_cold.astype(jnp.float32)
 
     y = constrain(y.astype(x.dtype), P(BATCH, None))

@@ -39,13 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
+from repro.core.predictor import SCORE_PRECISION
 from repro.kernels import default_interpret
 from repro.models.modules import activation_fn
-
-# Renamed TPUCompilerParams -> CompilerParams across jax releases; the
-# compat module resolves whichever this install provides.
-CompilerParams = pallas_tpu_compiler_params()
 
 
 def _kernel(idx_ref, x_ref, w_ref, o_ref, *, activation: str, gated: bool):
@@ -109,7 +105,7 @@ def cluster_gather_ffn(x, w, cluster_idx, *, activation: str,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(cluster_idx, x, w_blocked)
     return out.astype(x.dtype)
@@ -126,11 +122,31 @@ def cluster_gather_ffn(x, w, cluster_idx, *, activation: str,
 _NEG = float(jnp.finfo(jnp.float32).min)
 
 
+def _kernel_layout(w, dtype=None):
+    """(N, R, D) bundles -> the fused kernel's HBM layout (R, N, Dp).
+
+    The chip's DMA engine moves whole (sublane, lane) tiles, so a
+    cluster slice must cover full tiles in the two minor dimensions:
+    an R=3 second-minor and a D that is not a multiple of 128 both
+    fall inside a tile. Matrix-major rows with D padded to the lane
+    width make one cluster a (R, cs, Dp) slab of whole tiles. The pad
+    columns are zero and are never read back (the kernel loads [:D]).
+    """
+    N, R, D = w.shape
+    Dp = -(-D // 128) * 128
+    wk = jnp.swapaxes(w, 0, 1)
+    if dtype is not None:
+        wk = wk.astype(dtype)
+    if Dp != D:
+        wk = jnp.pad(wk, ((0, 0), (0, 0), (0, Dp - D)))
+    return wk
+
+
 def _fused_kernel(*refs, activation: str, gated: bool, cats: bool,
                   kc: int, nc_g: int, cs: int, quant: bool, mixed: bool):
     """One grid step = one neuron group: score -> top-k -> gathered FFN.
 
-    x_ref (B, D) VMEM; w_hbm (G*nc_g*cs, R, D) stays in HBM (ANY) —
+    x_ref (B, D) VMEM; w_hbm (R, G*nc_g*cs, Dp) stays in HBM (ANY) —
     clusters are pulled in by explicit double-buffered DMA; a_ref
     (D, r) / b_ref (r, nc_g*cs) the predictor slice for this group;
     mask_ref (B, 1) live-row mask; y_ref (B, D) fp32 accumulator over
@@ -140,8 +156,8 @@ def _fused_kernel(*refs, activation: str, gated: bool, cats: bool,
     holds the *stored* int8 codes — the cluster DMA moves int8 (3-4x
     fewer HBM bytes per bundle) and dequantize happens in VMEM right
     before the gated FFN dots: codes * per-row scale (wsc_ref, this
-    group's (nc_g*cs, R) block) plus, for int4-mixed, the FP16 outlier
-    sidecar (wout_hbm, double-buffered alongside the codes). The
+    group's (nc_g*cs, R) block) plus, for int4-mixed, the outlier
+    sidecar (wout_hbm, fp32, double-buffered alongside the codes). The
     formula matches sparse_ffn._gather_quant exactly, so jnp and
     pallas decode stay token-identical.
     """
@@ -156,6 +172,9 @@ def _fused_kernel(*refs, activation: str, gated: bool, cats: bool,
         x_ref, w_hbm, a_ref, b_ref, mask_ref, y_ref, idx_ref = refs
         wsc_ref = wout_hbm = None
     g = pl.program_id(0)
+    R = w_hbm.shape[0]
+    D = x_ref.shape[1]
+    n_cols = nc_g * cs
 
     @pl.when(g == 0)
     def _init():
@@ -166,37 +185,46 @@ def _fused_kernel(*refs, activation: str, gated: bool, cats: bool,
         # -- predictor scoring (fp32, matching core.predictor) --
         h = jax.lax.dot_general(
             x.astype(jnp.float32), a_ref[...].astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            (((1,), (0,)), ((), ())), precision=SCORE_PRECISION,
+            preferred_element_type=jnp.float32)
         scores = jax.lax.dot_general(
             h, b_ref[...].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
+            (((1,), (0,)), ((), ())), precision=SCORE_PRECISION,
             preferred_element_type=jnp.float32)           # (B, nc_g*cs)
-        # -- batch-union cluster scores (paper fn.1 + §3.1) --
-        union = jnp.where(mask_ref[...] > 0.0, scores, _NEG).max(axis=0)
-        cscore = union.reshape(nc_g, cs).max(axis=-1)     # (nc_g,)
+        # -- batch-union scores (paper fn.1 + §3.1), kept 2-D --
+        union = jnp.where(mask_ref[...] > 0.0, scores,
+                          _NEG).max(axis=0, keepdims=True)  # (1, n_cols)
+        col = jax.lax.broadcasted_iota(jnp.int32, union.shape, 1)
 
-        # -- iterative top-k: argmax + knock-out reproduces
-        #    jax.lax.top_k exactly (ties resolve to the lowest index) --
-        def select(k, sc):
-            c = jnp.argmax(sc).astype(jnp.int32)
+        # -- top-k over cluster maxima without a per-cluster reshape:
+        #    the cluster holding the first element equal to the global
+        #    max is the lowest-indexed cluster with the highest cluster
+        #    max, i.e. exactly jax.lax.top_k's pick (ties -> lowest
+        #    index); knocking that cluster's column window down to -inf
+        #    exposes the next one. --
+        for k in range(kc):
+            top = jnp.max(union)
+            first = jnp.min(jnp.where(union == top, col, n_cols))
+            c = first // cs
             idx_ref[g, k] = c
-            return sc.at[c].set(-jnp.inf)
-        jax.lax.fori_loop(0, kc, select, cscore, unroll=True)
+            lo = c * cs
+            union = jnp.where((col >= lo) & (col < lo + cs), -jnp.inf,
+                              union)
 
         # -- double-buffered gather + gated FFN --
         def code_dma(slot, k):
             c = idx_ref[g, k]
-            row = (g * nc_g + c) * cs
+            row = pl.multiple_of((g * nc_g + c) * cs, cs)
             return pltpu.make_async_copy(
-                w_hbm.at[pl.ds(row, cs)], buf.at[slot], sem.at[slot])
+                w_hbm.at[:, pl.ds(row, cs)], buf.at[slot], sem.at[slot])
 
         def sidecar_dma(slot, k):
-            # fp16 outlier sidecar rides its own DMA pair so the
-            # int8 code fetch stays a single contiguous burst
+            # the outlier sidecar rides its own DMA pair so the int8
+            # code fetch stays a single burst
             c = idx_ref[g, k]
-            row = (g * nc_g + c) * cs
+            row = pl.multiple_of((g * nc_g + c) * cs, cs)
             return pltpu.make_async_copy(
-                wout_hbm.at[pl.ds(row, cs)], obuf.at[slot],
+                wout_hbm.at[:, pl.ds(row, cs)], obuf.at[slot],
                 osem.at[slot])
 
         def dma_start(slot, k):
@@ -211,6 +239,9 @@ def _fused_kernel(*refs, activation: str, gated: bool, cats: bool,
 
         dma_start(0, 0)                                   # warm-up fetch
         act = activation_fn(activation)
+        if cats:
+            ncol = jax.lax.broadcasted_iota(jnp.int32, (n_cols, cs), 0)
+            jcol = jax.lax.broadcasted_iota(jnp.int32, (n_cols, cs), 1)
 
         def compute(k, _):
             slot = jax.lax.rem(k, 2)
@@ -220,35 +251,44 @@ def _fused_kernel(*refs, activation: str, gated: bool, cats: bool,
                 dma_start(jax.lax.rem(k + 1, 2), k + 1)
 
             dma_wait(slot, k)
-            wk = buf[slot]                                # (cs, R, D)
+            c = idx_ref[g, k]
             if quant:
-                # dequantize in VMEM, before the FFN dots: stored int8
-                # codes * this cluster's per-row scales (+ outliers)
-                c = idx_ref[g, k]
-                sc = jax.lax.dynamic_slice(
-                    wsc_ref[...], (c * cs, 0), (cs, wk.shape[1]))
-                wk = wk.astype(jnp.float32) * sc[:, :, None]
+                sc = wsc_ref[pl.ds(pl.multiple_of(c * cs, cs), cs), :]
+
+            def matrix(r):
+                # one (cs, D) matrix of the cluster bundle; quantized
+                # storage dequantizes in VMEM, before the FFN dots:
+                # stored int8 codes * per-row scale (+ outliers)
+                wr = buf[slot, r, :, :D]
+                if not quant:
+                    return wr
+                wr = wr.astype(jnp.float32) * sc[:, r:r + 1]
                 if mixed:
-                    wk = wk + obuf[slot].astype(jnp.float32)
-                wk = wk.astype(x_ref.dtype)
+                    wr = wr + obuf[slot, r, :, :D]
+                return wr.astype(x.dtype)
+
             gg = jax.lax.dot_general(
-                x, wk[:, 0], (((1,), (1,)), ((), ())),
+                x, matrix(0), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)       # (B, cs)
             hh = act(gg)
             if gated:
                 u = jax.lax.dot_general(
-                    x, wk[:, 1], (((1,), (1,)), ((), ())),
+                    x, matrix(1), (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 hh = hh * u
             if cats:
                 # CATS token gating: each token keeps only neurons its
                 # OWN predicted activation marks positive (§7.2.5) —
                 # the batch union steers selection, not computation.
-                c = idx_ref[g, k]
-                tok = jax.lax.dynamic_slice(
-                    scores, (0, c * cs), (scores.shape[0], cs))
+                # The cluster's score columns come out of an exact 0/1
+                # selection matmul instead of a dynamic lane slice.
+                pick = (ncol == c * cs + jcol).astype(jnp.float32)
+                tok = jax.lax.dot_general(
+                    scores, pick, (((1,), (0,)), ((), ())),
+                    precision=SCORE_PRECISION,
+                    preferred_element_type=jnp.float32)   # (B, cs)
                 hh = hh * (tok > 0.0).astype(hh.dtype)
-            wd = wk[:, -1]
+            wd = matrix(R - 1)
             y_ref[...] += jax.lax.dot_general(
                 hh.astype(wd.dtype), wd, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -256,17 +296,18 @@ def _fused_kernel(*refs, activation: str, gated: bool, cats: bool,
 
         jax.lax.fori_loop(0, kc, compute, 0)
 
+    slab = (2,) + w_hbm.shape[:1] + (cs,) + w_hbm.shape[2:]
     if mixed:
         pl.run_scoped(
             body,
-            buf=pltpu.VMEM((2, cs) + w_hbm.shape[1:], w_hbm.dtype),
+            buf=pltpu.VMEM(slab, w_hbm.dtype),
             sem=pltpu.SemaphoreType.DMA((2,)),
-            obuf=pltpu.VMEM((2, cs) + wout_hbm.shape[1:], wout_hbm.dtype),
+            obuf=pltpu.VMEM(slab, wout_hbm.dtype),
             osem=pltpu.SemaphoreType.DMA((2,)))
     else:
         pl.run_scoped(
             body,
-            buf=pltpu.VMEM((2, cs) + w_hbm.shape[1:], w_hbm.dtype),
+            buf=pltpu.VMEM(slab, w_hbm.dtype),
             sem=pltpu.SemaphoreType.DMA((2,)))
 
 
@@ -278,16 +319,17 @@ def fused_cold_ffn(x, w, A, Bp, mask, *, activation: str, cluster_size: int,
     """Fused cold path: score -> top-k -> gather -> FFN in one pallas_call.
 
     x (B, D); w (G*nc_g*cs, R, D) group-major cold bundles (HBM-resident
-    — never staged through the block pipeline); A (D, r) / Bp
-    (r, G*nc_g*cs) the cold predictor slice; mask (B, 1) float live-row
-    mask (1.0 = row steers the batch union).
+    — never staged through the block pipeline; handed to the kernel in
+    `_kernel_layout`); A (D, r) / Bp (r, G*nc_g*cs) the cold predictor
+    slice; mask (B, 1) float live-row mask (1.0 = row steers the batch
+    union).
 
     Quantized storage: pass the int8 codes as `w` plus `wsc`
     (G*nc_g*cs, R) fp32 per-row scales (staged per group through the
     block pipeline) and, for int4-mixed, `wout` (G*nc_g*cs, R, D) fp16
-    outlier sidecar (HBM-resident, DMA'd alongside the codes). The
-    cluster DMA then moves int8 and the kernel dequantizes in VMEM
-    before the FFN dots.
+    outlier sidecar (HBM-resident, DMA'd alongside the codes as fp32,
+    which holds every fp16 exactly). The cluster DMA then moves int8
+    and the kernel dequantizes in VMEM before the FFN dots.
 
     Returns (y (B, D) fp32, idx (groups, kc) int32) — bitwise the same
     selection as the jnp path's jax.lax.top_k chain.
@@ -304,20 +346,20 @@ def fused_cold_ffn(x, w, A, Bp, mask, *, activation: str, cluster_size: int,
     mixed = wout is not None
     in_specs = [
         pl.BlockSpec((B, D), lambda g: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),        # weights stay HBM
+        pl.BlockSpec(memory_space=pl.ANY),           # weights stay HBM
         pl.BlockSpec((D, r), lambda g: (0, 0)),
         pl.BlockSpec((r, nc_g * cluster_size),
                      lambda g: (0, g)),              # group's pred cols
         pl.BlockSpec((B, 1), lambda g: (0, 0)),
     ]
-    operands = [x, w, A, Bp, mask]
+    operands = [x, _kernel_layout(w), A, Bp, mask]
     if quant:
         in_specs.append(pl.BlockSpec((nc_g * cluster_size, R),
                                      lambda g: (g, 0)))  # group's scales
         operands.append(wsc)
         if mixed:
-            in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-            operands.append(wout)
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+            operands.append(_kernel_layout(wout, jnp.float32))
     y, idx = pl.pallas_call(
         functools.partial(_fused_kernel, activation=activation,
                           gated=R == 3, cats=cats, kc=kc, nc_g=nc_g,
@@ -329,7 +371,7 @@ def fused_cold_ffn(x, w, A, Bp, mask, *, activation: str, cluster_size: int,
         out_shape=(jax.ShapeDtypeStruct((B, D), jnp.float32),
                    jax.ShapeDtypeStruct((groups, kc), jnp.int32)),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(*operands)
     return y, idx

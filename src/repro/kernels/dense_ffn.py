@@ -12,9 +12,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import default_interpret
-from repro.kernels.cluster_gather_ffn import CompilerParams, _kernel
+from repro.kernels.cluster_gather_ffn import _kernel
 
 
 @functools.partial(jax.jit, static_argnames=("activation", "block_n",
@@ -45,7 +46,7 @@ def dense_ffn(x, w, *, activation: str, block_n: int = 512,
         out_specs=pl.BlockSpec((B, D), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(x, w)
     return out.astype(x.dtype)

@@ -22,7 +22,6 @@ import traceback         # noqa: E402
 import jax               # noqa: E402
 
 from repro.configs import get_config, ASSIGNED_ARCHS, INPUT_SHAPES  # noqa: E402
-from repro.compat import set_mesh                                   # noqa: E402
 from repro.launch.mesh import make_production_mesh                  # noqa: E402
 from repro.launch import input_specs as ispec                       # noqa: E402
 from repro.models.model import build_model                          # noqa: E402
@@ -112,7 +111,7 @@ def lower_target(arch: str, shape_name: str, multi_pod: bool,
         model = build_model(cfg)
         groups = mesh.shape["model"]
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             pspecs = ispec.param_specs(model, cfg, mesh,
                                        fsdp=fsdp and shape.kind == "train")
             batch = ispec.input_specs(cfg, shape, mesh)
@@ -205,7 +204,7 @@ def _cost_of(arch, shape_name, cfg, multi_pod):
     _blocks.UNROLL = True
     _attn.FLASH_FULL_BLOCKS = True
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             pspecs = ispec.param_specs(model, cfg, mesh,
                                        fsdp=shape.kind == "train"
                                        and cfg.param_count() > 5e10)

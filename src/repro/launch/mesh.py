@@ -9,19 +9,21 @@ touch jax device state (the dry-run sets XLA_FLAGS before any init).
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs of mesh-aware code paths."""
-    return make_mesh((1, 1), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_serving_mesh(n_model: int, n_data: int = 1):
@@ -32,16 +34,15 @@ def make_serving_mesh(n_model: int, n_data: int = 1):
     Uses the first n_data*n_model visible devices (on CPU runs, force
     them with XLA_FLAGS=--xla_force_host_platform_device_count=N before
     the first jax call)."""
-    import jax
     need = n_data * n_model
     avail = jax.device_count()
     if need > avail:
         raise ValueError(
             f"serving mesh ({n_data}, {n_model}) needs {need} devices "
             f"but only {avail} are visible")
-    return make_mesh((n_data, n_model), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2,
-                     devices=jax.devices()[:need])
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:need])
 
 
 def dispatch_groups(mesh) -> int:
@@ -72,9 +73,9 @@ def replica_submeshes(mesh):
     n_data = shape.get("data", 1)
     n_model = shape.get("model", 1)
     devs = np.asarray(mesh.devices).reshape(n_data, n_model)
-    return [make_mesh((1, n_model), ("data", "model"),
-                      axis_types=(AxisType.Auto,) * 2,
-                      devices=list(devs[r]))
+    return [jax.make_mesh((1, n_model), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2,
+                          devices=list(devs[r]))
             for r in range(n_data)]
 
 

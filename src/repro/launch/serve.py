@@ -4,7 +4,10 @@ Plan (offline §5) -> permute weights hot-first -> ServeEngine (online
 §4) -> batched generation with Best-of-N and continuous batching.
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
-      --reduced --offload 0.5 --bon 4 --max-new 32
+      --offload 0.5 --bon 4 --max-new 32
+
+--reduced (the default) serves the 2-layer CPU-sized variant of the
+config; --no-reduced serves the published config at full width.
 
 Tensor-parallel serving (DESIGN.md §3): pass --tp N to run the engine
 over an (1, N) device mesh — on CPU hosts force the devices first with
@@ -42,6 +45,7 @@ from repro.configs import get_config
 from repro.core.baselines import POWERINFER2
 from repro.core.io_model import UFS40, HOST_DMA
 from repro.core.planner import profile_activations
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.engine import ServeEngine
 from repro.serving.families import default_archs, serving_family
 
@@ -50,17 +54,39 @@ from repro.serving.families import default_archs, serving_family
 FAMILY_ARCHS = default_archs()
 
 
+def init_params(model, seed: int = 0, mesh=None):
+    """Seeded random params. With a mesh they are initialised sharded
+    in place by one jitted init (each device computes only its own
+    shard), so a model too large for one device never lands whole on
+    the first."""
+    key = jax.random.key(seed)
+    if mesh is None:
+        return model.init(key)
+    shapes = jax.eval_shape(model.init, key)
+    shardings = ServeEngine.param_shardings(model, shapes, mesh)
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
 def build_engine(arch: str, reduced: bool = True, offload: float = 0.5,
                  spec=POWERINFER2, storage=UFS40, profile: bool = False,
                  seed: int = 0, tp: int = 1, dp: int = 1,
                  backend: str = "jnp", storage_dtype: str = "fp16",
-                 **engine_kwargs):
+                 overrides: dict = None, **engine_kwargs):
+    """Build one ServeEngine for `arch`: seeded random params, the
+    offline plan, hot-first weights. `reduced` picks the CPU-sized
+    variant; `overrides` replaces config fields after that (a depth
+    cut that keeps every width, a dtype)."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
+    if overrides:
+        cfg = cfg.replace(**overrides)
     fam = serving_family(cfg)
+    if tp > 1 and "mesh" not in engine_kwargs:
+        from repro.launch.mesh import make_serving_mesh
+        engine_kwargs["mesh"] = make_serving_mesh(tp, dp)
     model = fam.make_model(cfg)
-    params = model.init(jax.random.key(seed))
+    params = init_params(model, seed, engine_kwargs.get("mesh"))
     freqs = None
     if profile and not cfg.num_experts:
         # dense-layer activation profiling; the MoE router needs none
@@ -77,9 +103,6 @@ def build_engine(arch: str, reduced: bool = True, offload: float = 0.5,
         # planner (or a bench) pinned later still trace the chosen
         # kernel path
         engine_kwargs.setdefault("backend", backend)
-    if tp > 1 and "mesh" not in engine_kwargs:
-        from repro.launch.mesh import make_serving_mesh
-        engine_kwargs["mesh"] = make_serving_mesh(tp, dp)
     if dp > 1:
         # always forward dp (tp=1 replicas are meshless — replica
         # routing is scheduler-level and needs no devices); with a
@@ -122,7 +145,10 @@ def main():
                     default="dense",
                     help="serving family; picks the default arch "
                          "unless --arch is given")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the 2-layer reduced variant (default); "
+                         "--no-reduced serves the published config")
     ap.add_argument("--offload", type=float, default=0.5)
     ap.add_argument("--bon", type=int, default=1)
     ap.add_argument("--max-new", type=int, default=32)
@@ -155,6 +181,7 @@ def main():
                          "and the storage plane prices I/O + residency "
                          "at the declared bundle bytes (§4.4)")
     args = ap.parse_args()
+    use_compile_cache()
 
     arch = args.arch or FAMILY_ARCHS[args.family]
     tp = args.tp
@@ -201,7 +228,7 @@ def main():
               f"per-backend "
               f"{[b['completed'] for b in rep.per_backend]} completed")
         print(f"wall time {wall:.1f}s for {rep.total_tokens} tokens "
-              f"(CPU jit)")
+              f"({jax.devices()[0].platform})")
         gw.close()
         return
     engine, cfg = build_engine(arch, args.reduced, args.offload,
@@ -234,7 +261,7 @@ def main():
               f"latency ms: p50 {pct['p50']*1e3:.2f} "
               f"p90 {pct['p90']*1e3:.2f} p99 {pct['p99']*1e3:.2f}")
         print(f"wall time {wall:.1f}s for {rep.total_tokens} tokens "
-              f"(CPU jit)")
+              f"({jax.devices()[0].platform})")
         engine.close()
         return
     res = engine.generate(prompt, max_new=args.max_new)
@@ -248,7 +275,9 @@ def main():
     print(f"latency ms: mean {pct['mean']*1e3:.2f} p50 {pct['p50']*1e3:.2f} "
           f"p90 {pct['p90']*1e3:.2f} p99 {pct['p99']*1e3:.2f}")
     print(f"wall time {res.wall_s:.1f}s for "
-          f"{int(np.sum(res.tokens >= 0))} tokens (CPU jit)")
+          f"{int(np.sum(res.tokens >= 0))} tokens "
+          f"({jax.devices()[0].platform})")
+    engine.close()
 
 
 if __name__ == "__main__":

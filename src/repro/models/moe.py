@@ -178,10 +178,12 @@ def _cold_cluster_counts(h, cfg: ModelConfig, n_hot_e: int, cs: int):
 
 
 def _combine_group(yb, slot, keep, topv):
-    """yb (E*C, D) expert outputs -> (T, D) weighted combine."""
+    """yb (E*C, D) expert outputs -> (T, D) fp32 weighted combine (the
+    expert-parallel path sums in fp32 too, so both layouts round to
+    the compute dtype once, after the whole sum)."""
     T, k = slot.shape
     yk = jnp.take(yb, slot.reshape(-1), axis=0).reshape(T, k, yb.shape[-1])
-    yk = yk * (topv * keep).astype(yk.dtype)[..., None]
+    yk = yk.astype(jnp.float32) * (topv * keep)[..., None]
     return yk.sum(axis=1)
 
 
@@ -223,7 +225,6 @@ def _moe_ep_shard_map(params, xt, cfg: ModelConfig, C: int, active_mask,
     id-only collective the dense cold path uses for its cluster ids.
     """
     from jax.sharding import PartitionSpec as PS
-    from repro.compat import shard_map
     from repro.sharding import current_mesh
 
     mesh = current_mesh()
@@ -262,8 +263,8 @@ def _moe_ep_shard_map(params, xt, cfg: ModelConfig, C: int, active_mask,
             h = act(g)
         yb = jnp.einsum("ecf,efd->ecd", h, wl[:, :, -1])
         yk = jnp.take(yb.reshape(e_loc * C, D), lslot, axis=0)
-        yk = yk.reshape(T, k, D) \
-            * (topv * sel.reshape(T, k)).astype(yk.dtype)[..., None]
+        yk = yk.reshape(T, k, D).astype(jnp.float32) \
+            * (topv * sel.reshape(T, k))[..., None]
         # psum in f32 (same rationale as _cold_path_shard_map); the
         # kept counts and aux loss are replicated global math — no
         # collective beyond the one output reduction (plus, for the
@@ -289,7 +290,7 @@ def _moe_ep_shard_map(params, xt, cfg: ModelConfig, C: int, active_mask,
     if active_mask is None:
         active_mask = jnp.ones((xt.shape[0],), bool)
     tr_spec = PS(None, None) if two_level else PS(None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PS(None, None), PS("model", None, None, None),
                   PS(None, None), PS(None)),
@@ -381,7 +382,7 @@ def apply_moe_ffn(params, x, cfg: ModelConfig,
     yg = jax.vmap(_combine_group)(
         yb.reshape(G, E * C, D), slot, keep, topv)
     yg = constrain(yg, P(BATCH, None, None))
-    y = yg.reshape(T, D)
+    y = yg.reshape(T, D).astype(xt.dtype)
     aux = auxg.mean()
 
     if "shared" in params:                                  # hot clusters

@@ -59,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from repro.compat import set_mesh
 from repro.configs.base import ModelConfig
 from repro.core.adaptation import BucketedDecoder, bucket_for
 from repro.core.baselines import SystemSpec, POWERINFER2
@@ -324,24 +323,31 @@ class ServeEngine:
         "wout": PartitionSpec(None, "model", None, None),
     }
 
-    def _shard_params(self, params):
-        """Place params on the mesh with the model's param sharding —
-        the bundled (L, N, R, D) FFN tensor and the predictor columns
-        row/col-split over 'model'; non-dividing dims replicate."""
+    @classmethod
+    def param_shardings(cls, model, params, mesh):
+        """The model's param sharding on `mesh`, one NamedSharding per
+        leaf of `params` (arrays or shapes) — the bundled (L, N, R, D)
+        FFN tensor and the predictor columns row/col-split over
+        'model'; non-dividing dims replicate."""
         from jax.sharding import NamedSharding
         from repro.sharding import _filter_spec
-        mesh, specs = self.mesh, self.model.param_spec()
+        specs = model.param_spec()
         ffn = params.get("layers", {}).get("ffn", {})
-        extra = {k: s for k, s in self._QUANT_FFN_SPECS.items() if k in ffn}
+        extra = {k: s for k, s in cls._QUANT_FFN_SPECS.items() if k in ffn}
         if extra and "ffn" in specs.get("layers", {}):
             specs = dict(specs, layers=dict(
                 specs["layers"],
                 ffn=dict(specs["layers"]["ffn"], **extra)))
+        return jax.tree.map(
+            lambda a, s: NamedSharding(
+                mesh, _filter_spec(s, mesh, shape=a.shape)),
+            params, specs)
 
-        def put(a, s):
-            fs = _filter_spec(s, mesh, shape=a.shape)
-            return jax.device_put(a, NamedSharding(mesh, fs))
-        return jax.tree.map(put, params, specs)
+    def _shard_params(self, params):
+        """Place params on the mesh (a no-op for params initialised
+        there)."""
+        return jax.device_put(
+            params, self.param_shardings(self.model, params, self.mesh))
 
     # ------------------------------------------------ legacy attributes ----
     # Storage-plane internals used to live on the engine; keep read
@@ -475,7 +481,7 @@ class ServeEngine:
             self._prefill_fns[key] = jax.jit(
                 lambda p, b: self.model.prefill(p, b, max_len=T))
         if self.mesh is not None:
-            with set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 return self._prefill_fns[key](self.params,
                                               {"tokens": tokens})
         return self._prefill_fns[key](self.params, {"tokens": tokens})
@@ -611,6 +617,15 @@ class ServeEngine:
         return StepResult(stats=st, tokens=tok_map,
                           admitted=[r.uid for r in admits], finished=done,
                           t_s=self.clock_s)
+
+    def next_logits(self, uids) -> np.ndarray:
+        """(len(uids), V) fp32 logits the running requests' next tokens
+        are sampled from — after a step(), that step's decode logits."""
+        if self.replicas is not None:
+            raise ValueError("next_logits reads one replica's KV slots; "
+                             "ask the replica engine")
+        rows = jnp.asarray(self.arena.rows_for(list(uids)), jnp.int32)
+        return np.asarray(self._last.take(rows, axis=0), np.float32)
 
     def cancel(self, uids):
         """Force-finish requests (Best-of-N early stop / client

@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.core.cache import NeuronCache
 from repro.core.clusters import HybridPlan
-from repro.core.coldstore import ColdStore
+from repro.core.coldstore import BundleLayout, ColdStore
 from repro.core.io_model import StorageModel, UFS40
 from repro.core.pipeline import ClusterTask, PrefetchExecutor, \
     simulate_pipeline
@@ -59,8 +59,9 @@ class FFNStorageView:
         self.rows = ffn_rows(cfg.activation)
 
     def bundles(self, params):
-        return [np.asarray(params["layers"]["ffn"]["w"][l])
-                for l in range(self.cfg.num_layers)]
+        """Per-layer bundle layouts of the (L, N, R, D) FFN tensor."""
+        w = params["layers"]["ffn"]["w"]
+        return [BundleLayout.of(w.shape[1:], w.dtype)] * w.shape[0]
 
     def deploy_neurons(self, timing) -> float:
         """Deployment-size flat neuron count per layer (streamed once
@@ -158,14 +159,14 @@ class MoEStorageView:
         self.rows = ffn_rows(cfg.activation)
 
     def bundles(self, params):
+        """Per-layer layouts of the flat [shared | routed] space."""
         moe = params["layers"]["moe"]
-        ex = np.asarray(moe["experts"])             # (L, E, f, R, D)
+        ex = moe["experts"]                         # (L, E, f, R, D)
         L, E, f, R, D = ex.shape
-        flat = ex.reshape(L, E * f, R, D)
+        n = E * f
         if "shared" in moe:
-            sh = np.asarray(moe["shared"]["w"])     # (L, n_sh*f, R, D)
-            flat = np.concatenate([sh, flat], axis=1)
-        return [flat[l] for l in range(L)]
+            n += moe["shared"]["w"].shape[1]        # (L, n_sh*f, R, D)
+        return [BundleLayout.of((n, R, D), ex.dtype)] * L
 
     def deploy_neurons(self, timing) -> float:
         # timing.d_ff is the deployment per-expert width; the expert

@@ -1,8 +1,7 @@
 """Sharding-constraint helpers usable from model code without a mesh.
 
 All model code calls `constrain(x, spec)`; outside a mesh context (CPU
-smoke tests) it is a no-op, inside `repro.compat.set_mesh(...)` (the
-`jax.set_mesh` shim) it becomes a `with_sharding_constraint`. Axis
+smoke tests) it is a no-op, inside `jax.set_mesh(...)` it becomes a `with_sharding_constraint`. Axis
 names: 'pod' (outer replica/data), 'data' (batch), 'model'
 (tensor/expert/neuron/seq shards).
 """
@@ -11,10 +10,18 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import current_mesh
 
 __all__ = ["current_mesh", "batch_axes", "constrain", "constrain_batch",
            "BATCH"]
+
+
+def current_mesh():
+    """The ambient mesh entered with `jax.set_mesh`, or None outside
+    any."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or m.empty:
+        return None
+    return m
 
 
 def batch_axes(mesh=None):

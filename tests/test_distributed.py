@@ -1,7 +1,6 @@
 """Distribution tests in a subprocess with 8 forced host devices
 (device count locks at first jax init, so the main test process stays
-single-device). Mesh/axis-type/shard_map API drift is absorbed by
-repro.compat, so these run on every supported jax."""
+single-device)."""
 import os
 import subprocess
 import sys
@@ -21,7 +20,8 @@ def run_in_subprocess(body: str, timeout=420, ndev=8):
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import AxisType, make_mesh, set_mesh
+        from jax import make_mesh, set_mesh
+        from jax.sharding import AxisType
     """ % ndev) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,

@@ -132,7 +132,7 @@ def test_dryrun_moe_decode_smoke():
     """The moe family's decode dry-run path end to end (adapt config,
     infer groups, lower the decode step on the mesh) — the cheap
     1-device half of the 256-device sweep guarantee."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.launch.dryrun import adapt_moe_groups, decode_plan_for
     from repro.launch.input_specs import cache_specs, param_specs
     from repro.models.model import build_model

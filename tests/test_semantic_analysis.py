@@ -76,9 +76,10 @@ def test_const_capture_fires_over_cap():
 
 
 def test_f64_fires_under_x64_ctx():
-    from jax.experimental import enable_x64
+    import jax
     e = _entry(lambda x: x.astype(jnp.float64),
-               (jnp.zeros((4,), jnp.float32),), trace_ctx=enable_x64)
+               (jnp.zeros((4,), jnp.float32),),
+               trace_ctx=lambda: jax.enable_x64(True))
     assert "jaxpr-f64" in rules_of(jaxpr_rules.run_entries([e]))
 
 

@@ -174,7 +174,8 @@ def test_shard_map_cold_path_matches_local():
 
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import AxisType, make_mesh, set_mesh
+    from jax import make_mesh, set_mesh
+    from jax.sharding import AxisType
     D, N, cs, G = 64, 512, 32, 4
     params = _params(D, N)
     x = jax.random.normal(jax.random.key(1), (2, D)) * 0.5
