@@ -41,7 +41,7 @@ import argparse
 import jax
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import ModelConfig, get_config
 from repro.core.baselines import POWERINFER2
 from repro.core.io_model import UFS40, HOST_DMA
 from repro.core.planner import profile_activations
@@ -67,20 +67,21 @@ def init_params(model, seed: int = 0, mesh=None):
     return jax.jit(model.init, out_shardings=shardings)(key)
 
 
-def build_engine(arch: str, reduced: bool = True, offload: float = 0.5,
-                 spec=POWERINFER2, storage=UFS40, profile: bool = False,
-                 seed: int = 0, tp: int = 1, dp: int = 1,
-                 backend: str = "jnp", storage_dtype: str = "fp16",
-                 overrides: dict = None, **engine_kwargs):
-    """Build one ServeEngine for `arch`: seeded random params, the
-    offline plan, hot-first weights. `reduced` picks the CPU-sized
-    variant; `overrides` replaces config fields after that (a depth
-    cut that keeps every width, a dtype)."""
-    cfg = get_config(arch)
-    if reduced:
-        cfg = cfg.reduced()
-    if overrides:
-        cfg = cfg.replace(**overrides)
+def build_engine(arch: str | ModelConfig, reduced: bool = True,
+                 offload: float = 0.5, spec=POWERINFER2, storage=UFS40,
+                 profile: bool = False, seed: int = 0, tp: int = 1,
+                 dp: int = 1, backend: str = "jnp",
+                 storage_dtype: str = "fp16", **engine_kwargs):
+    """Build one ServeEngine: seeded random params, the offline plan,
+    hot-first weights. `arch` is a registry id, of which `reduced`
+    picks the CPU-sized variant, or a ModelConfig served as given (a
+    depth cut that keeps every width, another dtype)."""
+    if isinstance(arch, ModelConfig):
+        cfg = arch
+    else:
+        cfg = get_config(arch)
+        if reduced:
+            cfg = cfg.reduced()
     fam = serving_family(cfg)
     if tp > 1 and "mesh" not in engine_kwargs:
         from repro.launch.mesh import make_serving_mesh

@@ -113,6 +113,7 @@ class StepResult:
     finished: list = field(default_factory=list)
     replica: int = 0                   # 'data'-axis row that stepped
     t_s: float = 0.0                   # that replica's clock after the step
+    trace: np.ndarray = None           # the activation trace it priced
 
 
 @dataclass
@@ -555,7 +556,7 @@ class ServeEngine:
                 tokens={g(i, u): t for u, t in r.tokens.items()},
                 admitted=[g(i, u) for u in r.admitted],
                 finished=[g(i, u) for u in r.finished],
-                replica=i, t_s=rep.clock_s)
+                replica=i, t_s=rep.clock_s, trace=r.trace)
         sched = self.sched
         if not sched.has_work:
             return None
@@ -601,7 +602,8 @@ class ServeEngine:
         ctx = float(np.mean([sched.sequences[u].prompt_len
                              + sched.sequences[u].n_generated
                              for u in sched.running]))
-        st = self.storage.step(np.asarray(cidx), plan_b, n_active, ctx)
+        trace = np.asarray(cidx)
+        st = self.storage.step(trace, plan_b, n_active, ctx)
         self.clock_s += st.effective_s
 
         tok_map = {u: int(feed[s])
@@ -616,7 +618,7 @@ class ServeEngine:
             self.arena.release(u)
         return StepResult(stats=st, tokens=tok_map,
                           admitted=[r.uid for r in admits], finished=done,
-                          t_s=self.clock_s)
+                          t_s=self.clock_s, trace=trace)
 
     def next_logits(self, uids) -> np.ndarray:
         """(len(uids), V) fp32 logits the running requests' next tokens

@@ -71,27 +71,46 @@ def test_fused_cold_ffn_compiles_for_v5e(one_chip, kc, B, cats, storage):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _compile_decode_step(sharding, cfg):
+    """Optimized HLO text of one pallas-backend serving decode step of
+    `cfg` at batch bucket 4, compiled for `sharding`'s device."""
+    from repro.serving.families import serving_family
+    fam = serving_family(cfg)
+    model = fam.make_model(cfg)
+    plan = fam.build_plan(cfg, backend="pallas").plan_for_batch(4)
+    place = (lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                            sharding=sharding))
+    params = jax.tree.map(place, jax.eval_shape(model.init,
+                                                jax.random.key(0)))
+    cache = jax.tree.map(place, jax.eval_shape(
+        lambda: model.init_cache(4, 64)))
+    step = fam.make_decode_step(cfg)
+    return jax.jit(lambda p, t, c, m: step(p, t, c, plan, m)).lower(
+        params, _spec(sharding, (4, 1), jnp.int32), cache,
+        _spec(sharding, (4,), jnp.bool_)).compile().as_text()
+
+
 def test_pallas_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     """One serving decode step of full-width smollm-135m cut to 2
     layers, pallas backend, batch bucket 4: the kernel is in the
     compiled step. Off the chip the kernels default to interpret mode,
     so the test steers them to the chip's lowering."""
     from repro.kernels import cluster_gather_ffn
-    from repro.serving.families import serving_family
     monkeypatch.setattr(cluster_gather_ffn, "default_interpret",
                         lambda: False)
-    cfg = SMOLLM.replace(num_layers=2)
-    fam = serving_family(cfg)
-    model = fam.make_model(cfg)
-    plan = fam.build_plan(cfg, backend="pallas").plan_for_batch(4)
-    place = (lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                            sharding=one_chip))
-    params = jax.tree.map(place, jax.eval_shape(model.init,
-                                                jax.random.key(0)))
-    cache = jax.tree.map(place, jax.eval_shape(
-        lambda: model.init_cache(4, 64)))
-    step = fam.make_decode_step(cfg)
-    compiled = jax.jit(lambda p, t, c, m: step(p, t, c, plan, m)).lower(
-        params, _spec(one_chip, (4, 1), jnp.int32), cache,
-        _spec(one_chip, (4,), jnp.bool_)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 1
+    text = _compile_decode_step(one_chip, SMOLLM.replace(num_layers=2))
+    assert text.count("tpu_custom_call") >= 1
+
+
+def test_fp32_pallas_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The same step held in fp32 at full matmul precision, as
+    chip_smoke.py serves both backends for its end-to-end gate: every
+    dot in the kernel then asks the chip for fp32 contraction."""
+    from repro.kernels import cluster_gather_ffn
+    monkeypatch.setattr(cluster_gather_ffn, "default_interpret",
+                        lambda: False)
+    cfg = SMOLLM.replace(num_layers=2, param_dtype="float32",
+                         compute_dtype="float32")
+    with jax.default_matmul_precision("highest"):
+        text = _compile_decode_step(one_chip, cfg)
+    assert text.count("tpu_custom_call") >= 1
