@@ -28,7 +28,7 @@ def main():
     rep = engine.run_until_drained()
     batches = [s.batch for s in rep.stats]
     print("batch timeline:", batches)
-    print("executable swaps:", engine.decoder.switches)
+    print("decode executables:", len(engine.decoder.live_plans()))
     print(f"modeled {rep.tokens_per_s:.1f} tok/s; "
           f"ttft {rep.ttft().mean() * 1e3:.2f} ms")
 

@@ -61,8 +61,6 @@ class BucketedDecoder:
     mesh: object = None
     backend: str = None
     _cache: Dict[tuple, tuple] = field(default_factory=dict)
-    switches: int = 0
-    _last_key: tuple = ()
 
     def prewarm(self):
         for b in self.buckets:
@@ -80,9 +78,6 @@ class BucketedDecoder:
             if self.mesh is not None:
                 fn = self._bind_mesh(fn, self.mesh)
             self._cache[key] = (plan, fn)
-        if key != self._last_key:
-            self.switches += 1
-            self._last_key = key
         return self._cache[key]
 
     @staticmethod

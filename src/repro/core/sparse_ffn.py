@@ -193,38 +193,41 @@ def _cold_path_shard_map(params, x, activation: str, mode: str,
                 **(_local_quant(qops) if quant else {}))
             return (jax.lax.psum(y.astype(jnp.float32), "model"),
                     jax.lax.all_gather(idx, "model").reshape(G, kc))
-        h = jnp.einsum("bd,dr->br", xl.astype(jnp.float32),
-                       Al.astype(jnp.float32), precision=SCORE_PRECISION)
-        scores = jnp.einsum("br,rn->bn", h, Bl.astype(jnp.float32),
-                            precision=SCORE_PRECISION)
-        union = jnp.where(maskl[:, None], scores,
-                          -jnp.inf).max(axis=0)       # (Nc_local,)
-        cscore = union.reshape(g_loc * nc_g, cs).max(axis=-1)
-        _, idx = jax.lax.top_k(cscore.reshape(g_loc, nc_g),
-                               kc)                    # (g_loc, kc)
-        if quant:
-            lq = _local_quant(qops)
-            gath = _gather_quant(lq["wq"], lq["wsc"], lq.get("wout"),
-                                 idx).astype(w.dtype)
-        else:
-            gath = jnp.take_along_axis(
-                wcl.reshape(g_loc, nc_g, cs, R, D),
-                idx[:, :, None, None, None], axis=1)  # (g_loc,kc,cs,R,D)
-        gath = gath.reshape(g_loc * kc * cs, R, D)
-        g = jnp.einsum("bd,kd->bk", xl, gath[:, 0],
-                       preferred_element_type=jnp.float32)
-        if R == 3:
-            u = jnp.einsum("bd,kd->bk", xl, gath[:, 1],
+        with jax.named_scope("predictor"):
+            h = jnp.einsum("bd,dr->br", xl.astype(jnp.float32),
+                           Al.astype(jnp.float32), precision=SCORE_PRECISION)
+            scores = jnp.einsum("br,rn->bn", h, Bl.astype(jnp.float32),
+                                precision=SCORE_PRECISION)
+            union = jnp.where(maskl[:, None], scores,
+                              -jnp.inf).max(axis=0)   # (Nc_local,)
+            cscore = union.reshape(g_loc * nc_g, cs).max(axis=-1)
+            _, idx = jax.lax.top_k(cscore.reshape(g_loc, nc_g),
+                                   kc)                # (g_loc, kc)
+        with jax.named_scope("cold_layout"):
+            if quant:
+                lq = _local_quant(qops)
+                gath = _gather_quant(lq["wq"], lq["wsc"], lq.get("wout"),
+                                     idx).astype(w.dtype)
+            else:
+                gath = jnp.take_along_axis(
+                    wcl.reshape(g_loc, nc_g, cs, R, D),
+                    idx[:, :, None, None, None], axis=1)  # (g_loc,kc,cs,R,D)
+            gath = gath.reshape(g_loc * kc * cs, R, D)
+        with jax.named_scope("cold_kernel"):
+            g = jnp.einsum("bd,kd->bk", xl, gath[:, 0],
                            preferred_element_type=jnp.float32)
-            hh = act(g) * u
-        else:
-            hh = act(g)
-        if mode == "cats":
-            tok = scores.reshape(-1, g_loc, nc_g, cs)
-            tok = jnp.take_along_axis(tok, idx[None, :, :, None], axis=2)
-            hh = hh * (tok.reshape(hh.shape) > 0.0).astype(hh.dtype)
-        y = jnp.einsum("bk,kd->bd", hh.astype(w.dtype), gath[:, -1],
-                       preferred_element_type=jnp.float32)
+            if R == 3:
+                u = jnp.einsum("bd,kd->bk", xl, gath[:, 1],
+                               preferred_element_type=jnp.float32)
+                hh = act(g) * u
+            else:
+                hh = act(g)
+            if mode == "cats":
+                tok = scores.reshape(-1, g_loc, nc_g, cs)
+                tok = jnp.take_along_axis(tok, idx[None, :, :, None], axis=2)
+                hh = hh * (tok.reshape(hh.shape) > 0.0).astype(hh.dtype)
+            y = jnp.einsum("bk,kd->bd", hh.astype(w.dtype), gath[:, -1],
+                           preferred_element_type=jnp.float32)
         # psum in f32: XLA:CPU's AllReducePromotion pass crashes on
         # bf16 all-reduce inside partial-manual shard_map (and f32
         # reduction is numerically better anyway).
@@ -274,7 +277,8 @@ def ffn_hybrid(params, x, activation: str, mode: str, plan: HybridPlan,
     y = jnp.zeros((B, D), jnp.float32)
 
     if n_hot > 0:
-        y += _apply_bundle(w[:n_hot], x, activation).astype(jnp.float32)
+        with jax.named_scope("ffn_hot"):
+            y += _apply_bundle(w[:n_hot], x, activation).astype(jnp.float32)
 
     n_cold = N - n_hot
     cs = plan.cluster_size
@@ -297,10 +301,12 @@ def ffn_hybrid(params, x, activation: str, mode: str, plan: HybridPlan,
             # the jnp chain below (selection bit-identical, output
             # within fp tolerance), one pallas_call per layer.
             from repro.kernels import ops as kops
-            wc = w[n_hot:].reshape(G, nc_g, cs, R, D)
+            with jax.named_scope("cold_layout"):
+                wc = w[n_hot:].reshape(G, nc_g, cs, R, D)
+            with jax.named_scope("predictor"):
+                Bp = params["pred"]["B"][:, n_hot:]
             y_cold, cidx = kops.fused_cold_ffn(
-                x, wc, params["pred"]["A"],
-                params["pred"]["B"][:, n_hot:],
+                x, wc, params["pred"]["A"], Bp,
                 activation=activation, mode=mode, kc=kc,
                 active_mask=active_mask,
                 **_quant_operands(params, n_hot, (G, nc_g, cs, R, D)))
@@ -309,59 +315,61 @@ def ffn_hybrid(params, x, activation: str, mode: str, plan: HybridPlan,
             if return_indices:
                 return y, cidx
             return y
-        scores = predict_scores(params["pred"], x)[:, n_hot:]   # (B, Nc) fp32
-        quant = "wq" in params
-        # Batch union (paper fn.1: a neuron is active if any token in
-        # the batch triggers it), then *cluster*-granular selection —
-        # the neuron cluster is the basic unit (§3.1).
-        if active_mask is not None:
-            union = jnp.where(active_mask[:, None], scores,
-                              -jnp.inf).max(axis=0)             # (Nc,)
-        else:
-            union = scores.max(axis=0)                          # (Nc,)
-        cscore = union.reshape(G, nc_g, cs).max(axis=-1)        # (G, nc_g)
-        cscore = constrain(cscore, P("model", None))
-        _, cidx = jax.lax.top_k(cscore, kc)                     # (G, kc)
-        if quant:
-            # gather the *stored* int8 codes and dequantize right at
-            # the gather boundary (cast back to w.dtype so downstream
-            # compute matches the in-place roundtrip held by w)
-            wq = params["wq"][n_hot:].reshape(G, nc_g, cs, R, D)
-            wq = constrain(wq, P("model", None, None, None, None))
-            wsc = params["wsc"][n_hot:].reshape(G, nc_g, cs, R)
-            wout = params.get("wout")
-            if wout is not None:
-                wout = wout[n_hot:].reshape(G, nc_g, cs, R, D)
-            gath = _gather_quant(wq, wsc, wout, cidx).astype(w.dtype)
-        else:
-            wc = w[n_hot:].reshape(G, nc_g, cs, R, D)
-            wc = constrain(wc, P("model", None, None, None, None))
-            gath = jnp.take_along_axis(
-                wc, cidx[:, :, None, None, None], axis=1)  # (G,kc,cs,R,D)
-        gath = gath.reshape(G, kc * cs, R, D)
-        act = activation_fn(activation)
-        # fp32 gate/up activations, bf16 operands for the down
-        # projection: the fused kernel's numerics, so the backends
-        # differ only in accumulation order
-        g = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 0],
-                       preferred_element_type=jnp.float32)
-        if R == 3:
-            u = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 1],
+        with jax.named_scope("predictor"):
+            scores = predict_scores(params["pred"], x)[:, n_hot:]  # (B, Nc)
+            # Batch union (paper fn.1: a neuron is active if any token
+            # in the batch triggers it), then *cluster*-granular
+            # selection — the neuron cluster is the basic unit (§3.1).
+            if active_mask is not None:
+                union = jnp.where(active_mask[:, None], scores,
+                                  -jnp.inf).max(axis=0)         # (Nc,)
+            else:
+                union = scores.max(axis=0)                      # (Nc,)
+            cscore = union.reshape(G, nc_g, cs).max(axis=-1)    # (G, nc_g)
+            cscore = constrain(cscore, P("model", None))
+            _, cidx = jax.lax.top_k(cscore, kc)                 # (G, kc)
+        with jax.named_scope("cold_layout"):
+            if "wq" in params:
+                # gather the *stored* int8 codes and dequantize right at
+                # the gather boundary (cast back to w.dtype so downstream
+                # compute matches the in-place roundtrip held by w)
+                wq = params["wq"][n_hot:].reshape(G, nc_g, cs, R, D)
+                wq = constrain(wq, P("model", None, None, None, None))
+                wsc = params["wsc"][n_hot:].reshape(G, nc_g, cs, R)
+                wout = params.get("wout")
+                if wout is not None:
+                    wout = wout[n_hot:].reshape(G, nc_g, cs, R, D)
+                gath = _gather_quant(wq, wsc, wout, cidx).astype(w.dtype)
+            else:
+                wc = w[n_hot:].reshape(G, nc_g, cs, R, D)
+                wc = constrain(wc, P("model", None, None, None, None))
+                gath = jnp.take_along_axis(
+                    wc, cidx[:, :, None, None, None], axis=1)  # (G,kc,cs,R,D)
+            gath = gath.reshape(G, kc * cs, R, D)
+        with jax.named_scope("cold_kernel"):
+            act = activation_fn(activation)
+            # fp32 gate/up activations, bf16 operands for the down
+            # projection: the fused kernel's numerics, so the backends
+            # differ only in accumulation order
+            g = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 0],
                            preferred_element_type=jnp.float32)
-            h = act(g) * u
-        else:
-            h = act(g)
-        if mode == "cats":
-            # CATS-style (§7.2.5): gate each token's contribution by
-            # its own predicted activation for the selected neurons.
-            tok = scores.reshape(B, G, nc_g, cs)
-            tok = jnp.take_along_axis(
-                tok, cidx[None, :, :, None], axis=2)    # (B,G,kc,cs)
-            h = h * (tok.reshape(B, G, kc * cs) > 0.0).astype(h.dtype)
-        y_cold = jnp.einsum("bgk,gkd->bd", h.astype(w.dtype),
-                            gath[:, :, -1],
-                            preferred_element_type=jnp.float32)
-        y += y_cold.astype(jnp.float32)
+            if R == 3:
+                u = jnp.einsum("bd,gkd->bgk", x, gath[:, :, 1],
+                               preferred_element_type=jnp.float32)
+                h = act(g) * u
+            else:
+                h = act(g)
+            if mode == "cats":
+                # CATS-style (§7.2.5): gate each token's contribution by
+                # its own predicted activation for the selected neurons.
+                tok = scores.reshape(B, G, nc_g, cs)
+                tok = jnp.take_along_axis(
+                    tok, cidx[None, :, :, None], axis=2)    # (B,G,kc,cs)
+                h = h * (tok.reshape(B, G, kc * cs) > 0.0).astype(h.dtype)
+            y_cold = jnp.einsum("bgk,gkd->bd", h.astype(w.dtype),
+                                gath[:, :, -1],
+                                preferred_element_type=jnp.float32)
+            y += y_cold.astype(jnp.float32)
 
     y = constrain(y.astype(x.dtype), P(BATCH, None))
     if return_indices:

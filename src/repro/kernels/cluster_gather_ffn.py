@@ -352,26 +352,30 @@ def fused_cold_ffn(x, w, A, Bp, mask, *, activation: str, cluster_size: int,
                      lambda g: (0, g)),              # group's pred cols
         pl.BlockSpec((B, 1), lambda g: (0, 0)),
     ]
-    operands = [x, _kernel_layout(w), A, Bp, mask]
+    with jax.named_scope("cold_layout"):
+        operands = [x, _kernel_layout(w), A, Bp, mask]
     if quant:
         in_specs.append(pl.BlockSpec((nc_g * cluster_size, R),
                                      lambda g: (g, 0)))  # group's scales
         operands.append(wsc)
         if mixed:
             in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-            operands.append(_kernel_layout(wout, jnp.float32))
-    y, idx = pl.pallas_call(
-        functools.partial(_fused_kernel, activation=activation,
-                          gated=R == 3, cats=cats, kc=kc, nc_g=nc_g,
-                          cs=cluster_size, quant=quant, mixed=mixed),
-        grid=(groups,),
-        in_specs=in_specs,
-        out_specs=(pl.BlockSpec((B, D), lambda g: (0, 0)),
-                   pl.BlockSpec(memory_space=pltpu.SMEM)),
-        out_shape=(jax.ShapeDtypeStruct((B, D), jnp.float32),
-                   jax.ShapeDtypeStruct((groups, kc), jnp.int32)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    )(*operands)
+            with jax.named_scope("cold_layout"):
+                operands.append(_kernel_layout(wout, jnp.float32))
+    with jax.named_scope("cold_kernel"):
+        y, idx = pl.pallas_call(
+            functools.partial(_fused_kernel, activation=activation,
+                              gated=R == 3, cats=cats, kc=kc, nc_g=nc_g,
+                              cs=cluster_size, quant=quant, mixed=mixed),
+            grid=(groups,),
+            in_specs=in_specs,
+            out_specs=(pl.BlockSpec((B, D), lambda g: (0, 0)),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)),
+            out_shape=(jax.ShapeDtypeStruct((B, D), jnp.float32),
+                       jax.ShapeDtypeStruct((groups, kc), jnp.int32)),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            name="fused_cold_ffn",
+        )(*operands)
     return y, idx

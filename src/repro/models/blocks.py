@@ -61,11 +61,12 @@ def _qkv(p, x, cfg: ModelConfig, angles, k_angles=None):
 
 def attn_full(p, x, cfg: ModelConfig, angles, *, causal=True, window=0):
     """Full-sequence self attention. Returns (out, (k, v)) for caching."""
-    q, k, v = _qkv(p, x, cfg, angles)
-    o = flash_attention(q, k, v, causal=causal, window=window)
-    B, S = x.shape[:2]
-    out = jnp.einsum("bse,ed->bsd", o.reshape(B, S, -1), p["wo"])
-    return constrain(out, P(BATCH, None, None)), (k, v)
+    with jax.named_scope("attention"):
+        q, k, v = _qkv(p, x, cfg, angles)
+        o = flash_attention(q, k, v, causal=causal, window=window)
+        B, S = x.shape[:2]
+        out = jnp.einsum("bse,ed->bsd", o.reshape(B, S, -1), p["wo"])
+        return constrain(out, P(BATCH, None, None)), (k, v)
 
 
 def attn_decode(p, x, cfg: ModelConfig, angles, k_cache, v_cache, kv_pos,
@@ -78,11 +79,13 @@ def attn_decode(p, x, cfg: ModelConfig, angles, k_cache, v_cache, kv_pos,
     Returns (out, k_cache', v_cache').
     """
     from repro.models.kv_cache import write_kv
-    q, k_new, v_new = _qkv(p, x, cfg, angles)
-    k_cache, v_cache = write_kv(k_cache, v_cache, k_new, v_new, pos)
-    o = decode_attention(q, k_cache, v_cache, kv_pos, pos, window=window)
-    out = jnp.einsum("bse,ed->bsd", o.reshape(*x.shape[:2], -1), p["wo"])
-    return constrain(out, P(BATCH, None, None)), k_cache, v_cache
+    with jax.named_scope("attention"):
+        q, k_new, v_new = _qkv(p, x, cfg, angles)
+        with jax.named_scope("kv_write"):
+            k_cache, v_cache = write_kv(k_cache, v_cache, k_new, v_new, pos)
+        o = decode_attention(q, k_cache, v_cache, kv_pos, pos, window=window)
+        out = jnp.einsum("bse,ed->bsd", o.reshape(*x.shape[:2], -1), p["wo"])
+        return constrain(out, P(BATCH, None, None)), k_cache, v_cache
 
 
 def cross_attn(p, x, mem_k, mem_v, cfg: ModelConfig):

@@ -84,14 +84,16 @@ def embed_tokens(params, cfg, tokens):
 
 
 def lm_logits(params, cfg, x):
-    x = rms_norm(x, params["out_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    if cfg.vocab_padded != cfg.vocab_size:
-        # mask the padding classes (vocab padded for shardability)
-        invalid = jnp.arange(cfg.vocab_padded) >= cfg.vocab_size
-        logits = jnp.where(invalid, jnp.asarray(-1e30, logits.dtype), logits)
-    return constrain(logits, P(BATCH, None, "model"))
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["out_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+        if cfg.vocab_padded != cfg.vocab_size:
+            # mask the padding classes (vocab padded for shardability)
+            invalid = jnp.arange(cfg.vocab_padded) >= cfg.vocab_size
+            logits = jnp.where(invalid, jnp.asarray(-1e30, logits.dtype),
+                               logits)
+        return constrain(logits, P(BATCH, None, "model"))
 
 
 def forward_from_embeds(params, cfg: ModelConfig, x, angles, *,

@@ -340,53 +340,57 @@ def apply_moe_ffn(params, x, cfg: ModelConfig,
     w = params["experts"]                                   # (E, f, R, D)
 
     if _use_ep_shard_map(cfg, G):
-        y, trace, aux = _moe_ep_shard_map(params, xt, cfg, C, active_mask,
-                                          plan=plan,
-                                          collect_trace=collect_trace)
+        with jax.named_scope("experts"):
+            y, trace, aux = _moe_ep_shard_map(
+                params, xt, cfg, C, active_mask, plan=plan,
+                collect_trace=collect_trace)
         if "shared" in params:                              # hot clusters
-            y = y + ffn_dense(params["shared"], xt, cfg.activation)
+            with jax.named_scope("ffn_hot"):
+                y = y + ffn_dense(params["shared"], xt, cfg.activation)
         y = y.reshape(shape)
         return (y, aux, trace) if collect_trace else (y, aux)
 
-    xg = constrain(xt.reshape(G, Tg, D), P(BATCH, None, None))
-    mask = jnp.ones((T,), bool) if active_mask is None \
-        else active_mask.reshape(-1)
-    buf, meta, auxg, cnts = jax.vmap(
-        lambda xx, mm: _dispatch_group(xx, params["router"], cfg, C, mm)
-    )(xg, mask.reshape(G, Tg))
+    with jax.named_scope("experts"):
+        xg = constrain(xt.reshape(G, Tg, D), P(BATCH, None, None))
+        mask = jnp.ones((T,), bool) if active_mask is None \
+            else active_mask.reshape(-1)
+        buf, meta, auxg, cnts = jax.vmap(
+            lambda xx, mm: _dispatch_group(xx, params["router"], cfg, C, mm)
+        )(xg, mask.reshape(G, Tg))
 
-    # explicit all-to-all: the dispatch buffer reshards from
-    # batch-sharded groups to expert-sharded slots — tokens move to the
-    # experts' shards instead of XLA all-gathering every expert's
-    # weights onto every data shard (§Perf iteration 3).
-    ep = cfg.moe_shard_mode == "ep"
-    espec = P(BATCH, "model", None, None) if ep \
-        else P(BATCH, None, None, None)
-    buf = constrain(buf, espec)
+        # explicit all-to-all: the dispatch buffer reshards from
+        # batch-sharded groups to expert-sharded slots — tokens move to the
+        # experts' shards instead of XLA all-gathering every expert's
+        # weights onto every data shard (§Perf iteration 3).
+        ep = cfg.moe_shard_mode == "ep"
+        espec = P(BATCH, "model", None, None) if ep \
+            else P(BATCH, None, None, None)
+        buf = constrain(buf, espec)
 
-    from repro.models.modules import activation_fn
-    act = activation_fn(cfg.activation)
-    R = w.shape[2]
-    g = jnp.einsum("gecd,efd->gecf", buf, w[:, :, 0])
-    g = constrain(g, P(BATCH, "model", None, None) if ep
-                  else P(BATCH, None, None, "model"))
-    if R == 3:
-        u = jnp.einsum("gecd,efd->gecf", buf, w[:, :, 1])
-        h = act(g) * u
-    else:
-        h = act(g)
-    yb = jnp.einsum("gecf,efd->gecd", h, w[:, :, -1])
-    # all-to-all back: expert-sharded outputs return to their groups
-    yb = constrain(yb, P(BATCH, None, None, None))
-    slot, keep, topv = meta
-    yg = jax.vmap(_combine_group)(
-        yb.reshape(G, E * C, D), slot, keep, topv)
-    yg = constrain(yg, P(BATCH, None, None))
-    y = yg.reshape(T, D).astype(xt.dtype)
-    aux = auxg.mean()
+        from repro.models.modules import activation_fn
+        act = activation_fn(cfg.activation)
+        R = w.shape[2]
+        g = jnp.einsum("gecd,efd->gecf", buf, w[:, :, 0])
+        g = constrain(g, P(BATCH, "model", None, None) if ep
+                      else P(BATCH, None, None, "model"))
+        if R == 3:
+            u = jnp.einsum("gecd,efd->gecf", buf, w[:, :, 1])
+            h = act(g) * u
+        else:
+            h = act(g)
+        yb = jnp.einsum("gecf,efd->gecd", h, w[:, :, -1])
+        # all-to-all back: expert-sharded outputs return to their groups
+        yb = constrain(yb, P(BATCH, None, None, None))
+        slot, keep, topv = meta
+        yg = jax.vmap(_combine_group)(
+            yb.reshape(G, E * C, D), slot, keep, topv)
+        yg = constrain(yg, P(BATCH, None, None))
+        y = yg.reshape(T, D).astype(xt.dtype)
+        aux = auxg.mean()
 
     if "shared" in params:                                  # hot clusters
-        y = y + ffn_dense(params["shared"], xt, cfg.activation)
+        with jax.named_scope("ffn_hot"):
+            y = y + ffn_dense(params["shared"], xt, cfg.activation)
     y = y.reshape(shape)
     if collect_trace:
         counts = cnts.sum(axis=0)                           # (E,) counts

@@ -67,6 +67,7 @@ from repro.core.planner import ExecutionPlan, HardwareProfile
 from repro.models.kv_cache import KVSlotArena
 from repro.models.modules import dtype_of
 from repro.serving.families import serving_family
+from repro.serving import spans
 from repro.serving.sampler import sample_tokens
 from repro.serving.scheduler import BatchScheduler
 from repro.serving.storage_plane import StoragePlane, TimingProfile, \
@@ -114,6 +115,7 @@ class StepResult:
     replica: int = 0                   # 'data'-axis row that stepped
     t_s: float = 0.0                   # that replica's clock after the step
     trace: np.ndarray = None           # the activation trace it priced
+    counts: dict = field(default_factory=dict)   # spans.COUNTS -> int
 
 
 @dataclass
@@ -283,10 +285,14 @@ class ServeEngine:
             params = self._shard_params(params)
         self.params = params
         self._step_traced = self.family.make_decode_step(cfg)
+
+        def make_step(p):
+            def decode(pr, t, c, m):
+                return self._step_traced(pr, t, c, p, m)
+            return decode
         self.decoder = BucketedDecoder(
             plan_source=plan,
-            make_step=lambda p: (lambda pr, t, c, m: self._step_traced(
-                pr, t, c, p, m)),
+            make_step=make_step,
             buckets=tuple(buckets) if buckets else tuple(range(1, 65)),
             mesh=mesh, backend=backend)
 
@@ -302,6 +308,8 @@ class ServeEngine:
         self.arena: Optional[KVSlotArena] = None
         self._last = None                  # (n_slots, V) next-token logits
         self._prefill_fns = {}
+        self._bucket = None                # bucket of the last decode
+        self._n_steps = 0
         self._temperature = temperature
         self.ctx_budget = ctx_budget
         self.clock_s = 0.0                 # modeled serving clock
@@ -441,7 +449,9 @@ class ServeEngine:
         req = self.sched.submit(prompt, max_new, arrival_time)
         return req.uid
 
-    def _ensure_arena(self, n_slots: int, min_len: int):
+    def _ensure_arena(self, n_slots: int, min_len: int) -> bool:
+        """Create the arena, or resize it to `n_slots`; True when it
+        resized."""
         cfg = self.cfg
         dtype = dtype_of(cfg.param_dtype)
         if self.arena is None:
@@ -471,6 +481,8 @@ class ServeEngine:
                                   self._last.dtype)
                 gat = jnp.concatenate([gat, zeros], axis=0)
             self._last = gat
+            return True
+        return False
 
     def _prefill(self, tokens: np.ndarray):
         """Jitted dense prefill padded to the arena length (traced and
@@ -479,15 +491,17 @@ class ServeEngine:
         T = self.arena.max_len
         key = (B, S, T)
         if key not in self._prefill_fns:
-            self._prefill_fns[key] = jax.jit(
-                lambda p, b: self.model.prefill(p, b, max_len=T))
+            def prefill(p, b):
+                with jax.named_scope("prefill"):
+                    return self.model.prefill(p, b, max_len=T)
+            self._prefill_fns[key] = jax.jit(prefill)
         if self.mesh is not None:
             with jax.set_mesh(self.mesh):
                 return self._prefill_fns[key](self.params,
                                               {"tokens": tokens})
         return self._prefill_fns[key](self.params, {"tokens": tokens})
 
-    def _admit(self, reqs: list):
+    def _admit(self, reqs: list, counts: dict):
         """Prefill-on-admit: joint prefill per prompt-length group,
         then write each request's KV row into a free slot."""
         i = 0
@@ -497,21 +511,33 @@ class ServeEngine:
             while i < len(reqs) and reqs[i].prompt_len == group[0].prompt_len:
                 group.append(reqs[i])
                 i += 1
-            tokens = np.stack([r.prompt for r in group]).astype(np.int32)
-            logits, cache = self._prefill(tokens)
-            self.clock_s += self.storage.prefill_cost(group[0].prompt_len,
-                                                      len(group))
-            for j, req in enumerate(group):
-                self.sched.admit(req, self.clock_s)
-                self.arena.alloc(req.uid)
-                row = {
-                    "k": cache["k"][:, j:j + 1],
-                    "v": cache["v"][:, j:j + 1],
-                    "kv_pos": cache["kv_pos"][j:j + 1],
-                    "length": cache["length"][j:j + 1],
-                }
-                slot = self.arena.write(req.uid, row)
-                self._last = self._last.at[slot].set(logits[j, -1])
+            S = group[0].prompt_len
+            counts["prefill_groups"] += 1
+            counts["prefill_tokens"] += S * len(group)
+            with spans.span(spans.PREFILL, uids=str([r.uid for r in group]),
+                            prompt_len=S, group=len(group)):
+                tokens = np.stack([r.prompt for r in group]).astype(np.int32)
+                logits, cache = self._prefill(tokens)
+                self.clock_s += self.storage.prefill_cost(S, len(group))
+                for j, req in enumerate(group):
+                    self.sched.admit(req, self.clock_s)
+                    self.arena.alloc(req.uid)
+                    row = {
+                        "k": cache["k"][:, j:j + 1],
+                        "v": cache["v"][:, j:j + 1],
+                        "kv_pos": cache["kv_pos"][j:j + 1],
+                        "length": cache["length"][j:j + 1],
+                    }
+                    slot = self.arena.write(req.uid, row)
+                    self._last = self._last.at[slot].set(logits[j, -1])
+
+    @staticmethod
+    def _pull(x, what: str, counts: dict) -> np.ndarray:
+        """Read a device array on the host: the one place a step waits
+        for the device."""
+        counts["pulls"] += 1
+        with spans.span(spans.PULL, what=what):
+            return np.asarray(x)
 
     # ------------------------------------------------------ decode loop ----
     def _next_replica(self) -> Optional[int]:
@@ -556,69 +582,94 @@ class ServeEngine:
                 tokens={g(i, u): t for u, t in r.tokens.items()},
                 admitted=[g(i, u) for u in r.admitted],
                 finished=[g(i, u) for u in r.finished],
-                replica=i, t_s=rep.clock_s, trace=r.trace)
+                replica=i, t_s=rep.clock_s, trace=r.trace, counts=r.counts)
+        if not self.sched.has_work:
+            return None
+        counts = dict.fromkeys(spans.COUNTS, 0)
+        with spans.span(spans.STEP, step=self._n_steps) as sp:
+            self._n_steps += 1
+            r = self._step(counts)
+            sp.set_metadata(**counts)
+        return r
+
+    def _step(self, counts: dict) -> Optional[StepResult]:
+        """The single-replica step, filling `counts`."""
         sched = self.sched
-        if not sched.has_work:
-            return None
-        # idle engine: jump the modeled clock to the next arrival
-        if not sched.running:
-            nxt = sched.next_arrival()
-            if nxt is not None and nxt > self.clock_s:
-                self.clock_s = nxt
-        room = self.max_slots - len(sched.running)
-        admits = sched.pop_admissible(self.clock_s, room)
-        n_active = len(sched.running) + len(admits)
-        if n_active == 0:
-            return None
-        # the KV arena tracks the decoder's bucket table: one resize
-        # (and at most one retrace) per boundary crossing. Its length is
-        # fixed at creation, so size it for everything already submitted
-        # (still-queued requests were never checked against an arena).
-        b = bucket_for(n_active, self.decoder.buckets)
-        need = [r.prompt_len + r.max_new for r in admits]
-        if self.arena is None:
-            need += [sched.sequences[u].prompt_len
-                     + sched.sequences[u].max_new for u in sched.queue]
-        self._ensure_arena(b, max(need, default=0))
-        if admits:
-            self._admit(admits)
+        built = len(self.decoder._cache) + len(self._prefill_fns)
+        with spans.span(spans.ADMIT) as sp:
+            # idle engine: jump the modeled clock to the next arrival
+            if not sched.running:
+                nxt = sched.next_arrival()
+                if nxt is not None and nxt > self.clock_s:
+                    self.clock_s = nxt
+            room = self.max_slots - len(sched.running)
+            admits = sched.pop_admissible(self.clock_s, room)
+            n_active = len(sched.running) + len(admits)
+            if n_active == 0:
+                return None
+            # the KV arena tracks the decoder's bucket table: one resize
+            # (and at most one retrace) per boundary crossing. Its length
+            # is fixed at creation, so size it for everything already
+            # submitted (still-queued requests were never checked
+            # against an arena).
+            b = bucket_for(n_active, self.decoder.buckets)
+            need = [r.prompt_len + r.max_new for r in admits]
+            if self.arena is None:
+                need += [sched.sequences[u].prompt_len
+                         + sched.sequences[u].max_new for u in sched.queue]
+            counts["resized"] = int(self._ensure_arena(b, max(need,
+                                                              default=0)))
+            if admits:
+                self._admit(admits, counts)
+            sp.set_metadata(admitted=len(admits))
         n_slots = self.arena.n_slots
+        counts["rows"], counts["live"] = n_slots, n_active
 
-        plan_b, step_fn = self.decoder.executable_for(n_active)
         rows = self.arena.rows_for(sched.running)
-        idx = jnp.asarray(rows, jnp.int32)
-        self.key, sk = jax.random.split(self.key)
-        toks_active = sample_tokens(sk, self._last.take(idx, axis=0),
-                                    self._temperature)        # (n_active,)
-        feed = np.zeros((n_slots,), np.int32)
-        feed[rows] = np.asarray(toks_active)
-        mask = np.zeros((n_slots,), bool)
-        mask[rows] = True
-        logits, cache, cidx = step_fn(self.params, jnp.asarray(feed)[:, None],
-                                      self.arena.cache, jnp.asarray(mask))
-        self.arena.cache = cache
-        self._last = logits[:, 0]
+        with spans.span(spans.SAMPLE):
+            idx = jnp.asarray(rows, jnp.int32)
+            self.key, sk = jax.random.split(self.key)
+            toks_active = sample_tokens(sk, self._last.take(idx, axis=0),
+                                        self._temperature)    # (n_active,)
+        toks_active = self._pull(toks_active, "tokens", counts)
+        with spans.span(spans.DECODE):
+            plan_b, step_fn = self.decoder.executable_for(n_active)
+            counts["switched"] = int(b != self._bucket)
+            self._bucket = b
+            feed = np.zeros((n_slots,), np.int32)
+            feed[rows] = toks_active
+            mask = np.zeros((n_slots,), bool)
+            mask[rows] = True
+            logits, cache, cidx = step_fn(self.params,
+                                          jnp.asarray(feed)[:, None],
+                                          self.arena.cache, jnp.asarray(mask))
+            self.arena.cache = cache
+            self._last = logits[:, 0]
+        counts["built"] = (len(self.decoder._cache) + len(self._prefill_fns)
+                           - built)
 
-        ctx = float(np.mean([sched.sequences[u].prompt_len
-                             + sched.sequences[u].n_generated
-                             for u in sched.running]))
-        trace = np.asarray(cidx)
-        st = self.storage.step(trace, plan_b, n_active, ctx)
-        self.clock_s += st.effective_s
+        trace = self._pull(cidx, "clusters", counts)
+        with spans.span(spans.PRICE):
+            ctx = float(np.mean([sched.sequences[u].prompt_len
+                                 + sched.sequences[u].n_generated
+                                 for u in sched.running]))
+            st = self.storage.step(trace, plan_b, n_active, ctx)
+            self.clock_s += st.effective_s
 
-        tok_map = {u: int(feed[s])
-                   for u, s in zip(sched.running, rows)}
-        for u in sched.running:
-            req = sched.sequences[u]
-            if req.first_token_time is None:
-                req.first_token_time = self.clock_s
-        done = sched.step(tok_map)
-        for u in done:
-            sched.sequences[u].finish_time = self.clock_s
-            self.arena.release(u)
+        with spans.span(spans.FINISH):
+            tok_map = {u: int(feed[s])
+                       for u, s in zip(sched.running, rows)}
+            for u in sched.running:
+                req = sched.sequences[u]
+                if req.first_token_time is None:
+                    req.first_token_time = self.clock_s
+            done = sched.step(tok_map)
+            for u in done:
+                sched.sequences[u].finish_time = self.clock_s
+                self.arena.release(u)
         return StepResult(stats=st, tokens=tok_map,
                           admitted=[r.uid for r in admits], finished=done,
-                          t_s=self.clock_s, trace=trace)
+                          t_s=self.clock_s, trace=trace, counts=counts)
 
     def next_logits(self, uids) -> np.ndarray:
         """(len(uids), V) fp32 logits the running requests' next tokens

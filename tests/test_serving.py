@@ -80,12 +80,22 @@ def test_bon_batch_decay_swaps_executables(setup):
     cfg, params, plan, prompt = setup
     eng = ServeEngine(cfg, params, plan, spec=POWERINFER2,
                       offload_ratio=0.5)
+    steps, step = [], eng.step
+
+    def recording_step():
+        r = step()
+        if r is not None:
+            steps.append(r)
+        return r
+
+    eng.step = recording_step
     res = eng.generate(prompt, max_new=12,
                        completion_schedule={3: 1, 6: 1, 9: 1})
     batches = [s.batch for s in res.stats]
     assert batches[0] == 4
     assert batches[-1] == 1
-    assert eng.decoder.switches >= 4
+    assert [r.stats.batch for r in steps] == batches
+    assert sum(r.counts["switched"] for r in steps) >= 4
 
 
 def test_deterministic_greedy(setup):

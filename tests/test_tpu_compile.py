@@ -102,6 +102,27 @@ def test_pallas_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") >= 1
 
 
+def test_v5e_decode_step_names_its_kernel_and_scopes(one_chip, monkeypatch):
+    """The compiled step keeps what the benchmark's trace readers look
+    for: the kernel's instruction named `fused_cold_ffn`
+    (`cold_ffn_roofline` matches it by name) under the `cold_kernel`
+    scope, and every decode scope in some op's op-name metadata."""
+    import re
+    from repro.kernels import cluster_gather_ffn
+    monkeypatch.setattr(cluster_gather_ffn, "default_interpret",
+                        lambda: False)
+    text = _compile_decode_step(one_chip, SMOLLM.replace(num_layers=2))
+    kernel = [line.strip() for line in text.splitlines()
+              if "tpu_custom_call" in line and " = " in line]
+    assert kernel and all(
+        re.match(r"^%?fused_cold_ffn[\w.]* = .* custom-call\(", line)
+        and "/cold_kernel/" in line for line in kernel)
+    scopes = {part for name in re.findall(r'op_name="([^"]*)"', text)
+              for part in name.split("/")}
+    assert {"attention", "kv_write", "ffn_hot", "predictor", "cold_layout",
+            "cold_kernel", "lm_head"} <= scopes
+
+
 def test_fp32_pallas_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     """The same step held in fp32 at full matmul precision, as
     chip_smoke.py serves both backends for its end-to-end gate: every
