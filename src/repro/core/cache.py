@@ -7,6 +7,8 @@ Three regions with different granularity and policy:
   * cold   — individually managed neurons for the sparse path; LRU at
              *neuron* granularity (co-activation after removing hot
              neurons is <20%, so bundling whole groups wastes I/O).
+             While every access is whole clusters on one grid, the
+             same LRU is held with one key per cluster (`ColdLRU`).
 
 Evictions are discards (weights are read-only; no write-back).
 `rebalance(batch_size)` grows the hot region for larger batches and
@@ -16,6 +18,8 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass
+
+import numpy as np
 
 
 @dataclass
@@ -78,6 +82,114 @@ class LRUSet:
         return list(self._d.keys())
 
 
+class ColdLRU:
+    """LRU over (layer, neuron_id) keys, capacity in neurons, that holds
+    whole clusters as one key while it can.
+
+    While the capacity is a multiple of `cluster_size` and every id set
+    it has been given is whole clusters on one grid (runs of
+    `cluster_size` ascending ids, each starting at the same offset
+    mod `cluster_size`), a neuron-keyed LRU keeps each cluster's
+    neurons adjacent in LRU order and all present or all absent, and
+    evicting the oldest `cluster_size` neurons evicts exactly one
+    cluster. So it is the same LRU with one key `(layer, first_id)`
+    per cluster. The first call that breaks this converts the state to
+    neuron keys, in LRU order, and from then on every key is a neuron.
+    Either way `keys()`, `len()`, the returned ids and the counts are
+    those of the neuron-keyed LRU.
+
+    `ops` counts the LRU keys touched or admitted: one per cluster
+    while clustered, one per neuron after."""
+
+    def __init__(self, capacity: int, cluster_size: int):
+        self.capacity = capacity
+        self.cs = cluster_size
+        self.off = None           # grid offset, fixed by the first ids
+        self.clustered = capacity > 0 and capacity % cluster_size == 0
+        self._lru = LRUSet(capacity // cluster_size if self.clustered
+                           else capacity)
+        self.ops = 0
+
+    def __len__(self):
+        return len(self._lru) * (self.cs if self.clustered else 1)
+
+    def keys(self):
+        if not self.clustered:
+            return self._lru.keys()
+        return [(layer, s + i) for layer, s in self._lru.keys()
+                for i in range(self.cs)]
+
+    def _clusters(self, ids):
+        """`ids` as a list of ints, and the first id of each whole
+        cluster it is made of; None for the latter (and the state
+        turned to neuron keys) if it is not whole clusters on this
+        LRU's grid."""
+        ids = np.asarray(ids, dtype=np.int64).tolist()
+        if not self.clustered:
+            return ids, None
+        cs = self.cs
+        starts = ids[::cs]
+        off = starts[0] % cs if starts else self.off
+        if self.off in (None, off) and all(
+                s % cs == off and ids[j:j + cs] == list(range(s, s + cs))
+                for j, s in zip(range(0, len(ids), cs), starts)):
+            self.off = off
+            return ids, starts
+        self._to_neurons()
+        return ids, None
+
+    def _to_neurons(self):
+        cs = self.cs
+        lru = LRUSet(self.capacity)
+        for layer, s in self._lru.keys():
+            for i in range(cs):
+                lru.admit((layer, s + i))
+        self._lru = lru
+        self.clustered = False
+
+    def lookup(self, layer: int, ids) -> tuple:
+        """Touch `ids`; returns (hit_ids, miss_ids), each in input order."""
+        ids, starts = self._clusters(ids)
+        if starts is None:
+            hits, misses = [], []
+            for nid in ids:
+                (hits if self._lru.touch((layer, nid))
+                 else misses).append(nid)
+            self.ops += len(ids)
+            return hits, misses
+        hit = [self._lru.touch((layer, s)) for s in starts]
+        self.ops += len(hit)
+        if all(hit):
+            return ids, []
+        if not any(hit):
+            return [], ids
+        cs = self.cs
+        hits, misses = [], []
+        for j, h in enumerate(hit):
+            (hits if h else misses).extend(ids[j * cs:(j + 1) * cs])
+        return hits, misses
+
+    def admit(self, layer: int, ids) -> int:
+        """Insert `ids` in order; returns the neurons evicted."""
+        ids, starts = self._clusters(ids)
+        keys = [(layer, nid) for nid in ids] if starts is None \
+            else [(layer, s) for s in starts]
+        self.ops += len(keys)
+        n = sum(len(self._lru.admit(k)) for k in keys)
+        return n if starts is None else n * self.cs
+
+    def resize(self, capacity: int) -> int:
+        """Set the capacity; returns the neurons evicted."""
+        if self.clustered and capacity > 0 and capacity % self.cs == 0:
+            n = len(self._lru.resize(capacity // self.cs)) * self.cs
+        else:
+            if self.clustered:
+                self._to_neurons()
+            n = len(self._lru.resize(capacity))
+        self.capacity = capacity
+        return n
+
+
 class NeuronCache:
     """Per-layer segmented neuron cache.
 
@@ -95,7 +207,7 @@ class NeuronCache:
         self.bytes_per_neuron = bytes_per_neuron
         n_hot = int(capacity_neurons * hot_fraction)
         self.hot = LRUSet(max(n_hot // cluster_size, 1))
-        self.cold = LRUSet(max(capacity_neurons - n_hot, 1))
+        self.cold = ColdLRU(max(capacity_neurons - n_hot, 1), cluster_size)
         self.stats = CacheStats()
 
     # -- hot region: cluster granularity ------------------------------
@@ -113,17 +225,13 @@ class NeuronCache:
     # -- cold region: neuron granularity ------------------------------
     def lookup_cold(self, layer: int, neuron_ids) -> tuple:
         """Returns (hit_ids, miss_ids)."""
-        hits, misses = [], []
-        for nid in neuron_ids:
-            (hits if self.cold.touch((layer, int(nid))) else misses).append(int(nid))
+        hits, misses = self.cold.lookup(layer, neuron_ids)
         self.stats.hits += len(hits)
         self.stats.misses += len(misses)
         return hits, misses
 
     def admit_cold(self, layer: int, neuron_ids):
-        for nid in neuron_ids:
-            ev = self.cold.admit((layer, int(nid)))
-            self.stats.evictions += len(ev)
+        self.stats.evictions += self.cold.admit(layer, neuron_ids)
         self.stats.bytes_loaded += len(neuron_ids) * self.bytes_per_neuron
 
     # -- dynamic rebalancing (paper §4.2 last para) --------------------
@@ -136,7 +244,7 @@ class NeuronCache:
         n_hot = int(self.capacity * hot_frac)
         ev_h = self.hot.resize(max(n_hot // self.cluster_size, 1))
         ev_c = self.cold.resize(max(self.capacity - n_hot, 1))
-        self.stats.evictions += len(ev_h) * self.cluster_size + len(ev_c)
+        self.stats.evictions += len(ev_h) * self.cluster_size + ev_c
 
     @property
     def resident_neurons(self) -> int:

@@ -653,7 +653,9 @@ class ServeEngine:
             ctx = float(np.mean([sched.sequences[u].prompt_len
                                  + sched.sequences[u].n_generated
                                  for u in sched.running]))
+            ops = self.storage.key_ops
             st = self.storage.step(trace, plan_b, n_active, ctx)
+            counts["price_keys"] = self.storage.key_ops - ops
             self.clock_s += st.effective_s
 
         with spans.span(spans.FINISH):

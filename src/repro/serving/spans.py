@@ -26,9 +26,11 @@ SPANS = (STEP, ADMIT, PREFILL, SAMPLE, PULL, DECODE, PRICE, FINISH)
 SCOPES = ("attention", "kv_write", "ffn_hot", "predictor", "cold_layout",
           "cold_kernel", "experts", "lm_head", "sample", "prefill")
 
-# StepResult.counts keys, plain ints per step
+# StepResult.counts keys, plain ints per step; `price_keys` is the cold
+# LRU keys the storage plane touched or admitted pricing the step (one
+# per cluster while its accesses are whole clusters, else per neuron)
 COUNTS = ("rows", "live", "prefill_groups", "prefill_tokens", "pulls",
-          "built", "resized", "switched")
+          "built", "resized", "switched", "price_keys")
 
 # span(name, **args): a host span, its args recorded with it
 span = jax.profiler.TraceAnnotation

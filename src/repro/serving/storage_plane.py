@@ -444,7 +444,7 @@ class StoragePlane:
         for l in range(cfg.num_layers):
             ids = self.view.warm_cold_ids(self.n_hot, per_layer)
             for s, part in enumerate(self._split_by_owner(ids, plan1)):
-                self.caches[s].admit_cold(l, list(part))
+                self.caches[s].admit_cold(l, part)
         for c in self.caches:
             c.stats.reset()
         self.coldstore.reset_stats()
@@ -463,6 +463,13 @@ class StoragePlane:
     def cache(self):
         """Shard 0's cache — the whole cache when n_shards == 1."""
         return self.caches[0]
+
+    @property
+    def key_ops(self) -> int:
+        """Cold LRU keys touched or admitted so far, over every shard's
+        cache (`ColdLRU.ops`); a step's difference is its
+        `price_keys` count."""
+        return sum(c.cold.ops for c in self.caches)
 
     @property
     def resident_capacity_neurons(self) -> int:

@@ -1,5 +1,8 @@
 """Property-based tests (hypothesis) for the cache, pipeline, planner,
 MoE dispatch, and I/O model invariants."""
+import math
+from collections import OrderedDict
+
 import numpy as np
 import pytest
 
@@ -65,6 +68,177 @@ def test_neuron_cache_repeat_requests_hit():
     nc.admit_cold(0, m1)
     h2, m2 = nc.lookup_cold(0, ids)
     assert m2 == [] and len(h2) == 32
+
+
+class RefColdCache:
+    """The cold region of NeuronCache as a plain per-neuron LRU: one
+    OrderedDict key per (layer, neuron), touched, admitted and evicted
+    one neuron at a time. The hot region is never used here, so it
+    adds no evictions."""
+
+    def __init__(self, capacity, bytes_per_neuron):
+        self.capacity = capacity
+        self.cold_cap = capacity
+        self.bpn = bytes_per_neuron
+        self.d = OrderedDict()
+        self.hits = self.misses = self.evictions = self.bytes_loaded = 0
+        self.ops = 0
+
+    def _evict_to(self, n):
+        while len(self.d) > n:
+            self.d.popitem(last=False)
+            self.evictions += 1
+
+    def lookup(self, layer, ids):
+        hits, misses = [], []
+        for nid in ids:
+            k = (layer, int(nid))
+            if k in self.d:
+                self.d.move_to_end(k)
+                hits.append(int(nid))
+            else:
+                misses.append(int(nid))
+        self.hits += len(hits)
+        self.misses += len(misses)
+        self.ops += len(ids)
+        return hits, misses
+
+    def admit(self, layer, ids):
+        for nid in ids:
+            k = (layer, int(nid))
+            if k in self.d:
+                self.d.move_to_end(k)
+                continue
+            self._evict_to(max(self.cold_cap, 1) - 1)
+            self.d[k] = True
+        self.bytes_loaded += len(ids) * self.bpn
+        self.ops += len(ids)
+
+    def rebalance(self, batch):
+        t = min(math.log2(max(batch, 1)) / 5.0, 1.0)
+        self.cold_cap = max(self.capacity - int(self.capacity
+                                                * (0.5 + 0.3 * t)), 1)
+        self._evict_to(self.cold_cap)
+
+
+CS, N_LAYERS, N_NEURONS = 4, 2, 64
+
+
+def _cold_cache_calls(draw, kind):
+    """A sequence of cold-region calls. `aligned`: whole clusters on one
+    grid, and a capacity that stays a multiple of CS; `unaligned`:
+    loose ids, clusters on another grid or on two, clusters short of a
+    neuron, or float ids, from the first call; `mixed`: aligned, then
+    unaligned. Ids come as lists or as int64 arrays."""
+    off = draw(st.integers(0, CS - 1))
+
+    def clusters(o):
+        cl = draw(st.lists(st.integers(0, N_NEURONS // CS - 2), max_size=5))
+        return [o + c * CS + i for c in cl for i in range(CS)]
+
+    def unaligned():
+        how = draw(st.sampled_from(["loose", "shifted", "straddle",
+                                    "overlap", "short"]))
+        if how == "loose":
+            return draw(st.lists(st.integers(0, N_NEURONS - 1),
+                                 max_size=12))
+        d = draw(st.integers(1, CS - 1))
+        shifted = (off + d) % CS
+        if how == "shifted":
+            return clusters(shifted)
+        if how == "straddle":
+            return clusters(off) + clusters(shifted)
+        if how == "overlap":
+            # a cluster on the grid, then one shifted off it that
+            # shares CS - d of its neurons
+            s = off + draw(st.integers(0, N_NEURONS // CS - 2)) * CS
+            return clusters(off) + [s + i for i in range(CS)] \
+                + [s + d + i for i in range(CS)]
+        return clusters(off)[:-1]
+
+    n = draw(st.integers(1, 25))
+    switch = {"aligned": n, "unaligned": 0,
+              "mixed": draw(st.integers(1, n))}[kind]
+    calls = []
+    for j in range(n):
+        op = draw(st.sampled_from(["lookup", "admit", "price", "rebalance"]))
+        if op == "rebalance":
+            batches = [1, 32] if j < switch else [1, 2, 4, 8, 16, 32]
+            calls.append((op, draw(st.sampled_from(batches))))
+        else:
+            layer = draw(st.integers(0, N_LAYERS - 1))
+            if j < switch:
+                ids = clusters(off)
+                dtype = draw(st.sampled_from([None, np.int64]))
+            else:
+                ids = unaligned()
+                dtype = draw(st.sampled_from([None, np.int64, np.float64]))
+            if dtype is not None:
+                ids = np.asarray(ids, dtype=dtype)
+            calls.append((op, layer, ids))
+    return calls
+
+
+@pytest.mark.parametrize("kind", ["aligned", "unaligned", "mixed"])
+@settings(max_examples=80, deadline=None)
+@given(data=st.data())
+def test_neuron_cache_cold_region_matches_per_neuron_lru(kind, data):
+    # a capacity of 10 * CS * k keeps the cold region whole clusters
+    # under rebalance(1) and rebalance(32), so the cluster keys hold
+    # until a `mixed` sequence turns unaligned
+    cap = data.draw(st.integers(1, 3)) * 10 * CS if kind != "unaligned" \
+        else data.draw(st.integers(1, 120))
+    nc = NeuronCache(N_LAYERS, N_NEURONS, CS, capacity_neurons=cap,
+                     hot_fraction=0.0, bytes_per_neuron=3)
+    ref = RefColdCache(cap, 3)
+    for call in _cold_cache_calls(data.draw, kind):
+        if call[0] == "rebalance":
+            nc.rebalance(call[1])
+            ref.rebalance(call[1])
+        else:
+            op, layer, ids = call
+            if op in ("lookup", "price"):
+                got = nc.lookup_cold(layer, ids)
+                want = ref.lookup(layer, ids)
+                assert got == want
+                if op == "price":
+                    nc.admit_cold(layer, got[1])
+                    ref.admit(layer, want[1])
+            else:
+                nc.admit_cold(layer, ids)
+                ref.admit(layer, ids)
+        s = nc.stats
+        assert (s.hits, s.misses, s.evictions, s.bytes_loaded) == (
+            ref.hits, ref.misses, ref.evictions, ref.bytes_loaded)
+        assert nc.cold.capacity == ref.cold_cap
+        assert len(nc.cold) == len(ref.d) == nc.resident_neurons
+        assert nc.cold.keys() == list(ref.d)
+    if kind == "aligned":
+        # the cluster path held throughout: one key per cluster
+        assert nc.cold.clustered
+        assert nc.cold.ops * CS == ref.ops
+
+
+@pytest.mark.parametrize("ids", [
+    [0, 1, 2, 3, 2, 3, 4, 5],       # a held cluster, then one off its grid
+    [8, 9, 10, 11, 1, 2, 3, 4],     # a new cluster, then one off the grid
+    [1, 2, 3, 4],                   # one whole cluster on another grid
+])
+def test_neuron_cache_cold_region_leaves_clusters_off_the_grid(ids):
+    """Runs of CS ids that overlap the held clusters' neurons are not
+    clusters of the grid: the cold region turns to neuron keys and
+    prices them as the per-neuron LRU does."""
+    nc = NeuronCache(1, N_NEURONS, CS, capacity_neurons=10 * CS,
+                     hot_fraction=0.0, bytes_per_neuron=1)
+    ref = RefColdCache(10 * CS, 1)
+    for call in ([0, 1, 2, 3], ids):
+        got = nc.lookup_cold(0, call)
+        assert got == ref.lookup(0, call)
+        nc.admit_cold(0, got[1])
+        ref.admit(0, got[1])
+    assert not nc.cold.clustered
+    assert nc.cold.keys() == list(ref.d)
+    assert nc.stats.evictions == ref.evictions == 0
 
 
 # -------------------------------------------------------------- pipeline ----

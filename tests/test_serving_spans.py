@@ -82,14 +82,22 @@ def test_traced_steps_record_every_span_nested_and_counted(engine, tmp_path):
         assert sum(s[3]["group"] for s in prefills) == len(r.admitted)
         assert admit[3]["admitted"] == len(r.admitted)
 
+    cs = cfg.sparse_ffn.cluster_size
+    for (_, _, _, args), r in zip(steps, results):
+        # whole clusters in every layer: one cold LRU key per layer's
+        # looked-up cluster and one per cluster admitted on a miss
+        assert args["price_keys"] == r.counts["price_keys"] \
+            == cfg.num_layers + r.stats.n_miss // cs
     a, b, c = (r.counts for r in results)
     assert a == dict(rows=2, live=2, prefill_groups=0, prefill_tokens=0,
-                     pulls=2, built=0, resized=0, switched=0)
+                     pulls=2, built=0, resized=0, switched=0,
+                     price_keys=a["price_keys"])
     # two prompt lengths admitted: two prefill groups, a new bucket,
     # its decode executable and one prefill executable per group shape
     assert b == dict(rows=4, live=4, prefill_groups=2, prefill_tokens=24,
-                     pulls=2, built=3, resized=1, switched=1)
-    assert c == dict(a, rows=4, live=4)
+                     pulls=2, built=3, resized=1, switched=1,
+                     price_keys=b["price_keys"])
+    assert c == dict(a, rows=4, live=4, price_keys=c["price_keys"])
     assert [s[3]["step"] for s in steps] == [1, 2, 3]
 
 
@@ -102,6 +110,7 @@ def test_counts_pass_through_replica_routing():
         r = eng.step()
         assert r.counts["pulls"] == 2 and r.counts["live"] == 1
         assert r.counts["switched"] == 1
+        assert r.counts["price_keys"] >= cfg.num_layers
     finally:
         eng.close()
 
