@@ -40,25 +40,37 @@ def attn_flops_token(cfg: dict, ctx: int) -> int:
     return proj + 4 * H * dh * ctx
 
 
+def dense_ffn_flops_token(cfg: dict) -> int:
+    """One layer's whole dense gated FFN of `intermediate_size` for
+    one token. A hot/cold plan's skipped neurons are not subtracted."""
+    return 3 * 2 * cfg["hidden_size"] * cfg["intermediate_size"]
+
+
 def ffn_flops_token(cfg: dict) -> int:
-    """One layer's FFN for one token, as the model defines it: the
-    whole dense FFN, or router + shared experts + top-k routed
-    experts. A hot/cold plan's skipped neurons are not subtracted."""
+    """One layer's FFN for one token, as the model defines it past its
+    leading dense layers: the whole dense FFN, or router + shared
+    experts + top-k routed experts."""
+    if not cfg.get("n_routed_experts"):
+        return dense_ffn_flops_token(cfg)
     D = cfg["hidden_size"]
-    if cfg.get("n_routed_experts"):
-        f = cfg["moe_intermediate_size"]
-        E, k = cfg["n_routed_experts"], cfg["num_experts_per_tok"]
-        ns = cfg.get("n_shared_experts", 0)
-        return 2 * D * E + (ns + k) * 3 * 2 * D * f
-    return 3 * 2 * D * cfg["intermediate_size"]
+    f = cfg["moe_intermediate_size"]
+    E, k = cfg["n_routed_experts"], cfg["num_experts_per_tok"]
+    ns = cfg.get("n_shared_experts", 0)
+    return 2 * D * E + (ns + k) * 3 * 2 * D * f
 
 
 def model_flops_token(cfg: dict, ctx: int) -> int:
     """Model FLOPs to decode one token whose attention spans `ctx`
-    positions (its own included): every layer, then the LM head."""
+    positions (its own included): every layer, then the LM head.
+    Layers [0, first_k_dense_replace) of an expert model have a dense
+    FFN of `intermediate_size`; the rest, `ffn_flops_token`."""
     L = cfg["num_hidden_layers"]
+    lead = min(cfg.get("first_k_dense_replace", 0), L) \
+        if cfg.get("n_routed_experts") else 0
     head = 2 * cfg["hidden_size"] * cfg["vocab_size"]
-    return L * (attn_flops_token(cfg, ctx) + ffn_flops_token(cfg)) + head
+    dense = dense_ffn_flops_token(cfg) if lead else 0
+    return (L * attn_flops_token(cfg, ctx) + lead * dense
+            + (L - lead) * ffn_flops_token(cfg) + head)
 
 
 def cold_kernel_call(*, rows: int, d_model: int, rank: int,

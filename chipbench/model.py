@@ -8,6 +8,11 @@ drawn here from the seed (uniform, with the variance of a
 norm reads as 1), so the plain reference can make the same arrays
 without taking anything the program made. They are checked against
 the shapes of the program's own `init` before they are served.
+
+The tree drawn is the cell's reference's `leaves(pc, cfg)` where it
+declares one (a layer kind with weights of its own: a leading dense
+FFN, latent attention's projections), else `default_leaves(pc)`, the
+Llama-style dense block or the plain MoE block.
 """
 from __future__ import annotations
 
@@ -25,8 +30,12 @@ def key32(seed: int):
                               (seed >> 32) & 0x7FFFFFFF)
 
 
-def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
+def program_config(cfg: dict, reference=None):
+    """The program's ModelConfig for a configuration file: its keys
+    mapped to the program's fields, then `serve.program`, an object of
+    ModelConfig field -> value, for fields no key maps. A file with
+    leading dense layers needs a `reference` that declares their
+    weights (`leaves`)."""
     import dataclasses
     from repro.configs import get_config
     s = cfg["serve"]
@@ -42,10 +51,14 @@ def program_config(cfg: dict):
               rope_theta=float(cfg["rope_theta"]),
               activation=cfg["hidden_act"],
               param_dtype=s["dtype"], compute_dtype=s["dtype"])
+    if cfg.get("first_k_dense_replace", 0) and \
+            not hasattr(reference, "leaves"):
+        raise ValueError(
+            "first_k_dense_replace is "
+            f"{cfg['first_k_dense_replace']}: the configuration's "
+            "reference must declare the leading dense layers' weights "
+            "in leaves(pc, cfg)")
     if cfg.get("n_routed_experts"):
-        if cfg.get("first_k_dense_replace", 0):
-            raise ValueError("the program's MoE has no leading dense "
-                             "layer; state first_k_dense_replace 0")
         kw.update(d_ff=cfg["moe_intermediate_size"],
                   num_experts=cfg["n_routed_experts"],
                   num_shared_experts=cfg["n_shared_experts"],
@@ -57,11 +70,21 @@ def program_config(cfg: dict):
                             "hot_ratio", "cold_active_ratio") if k in s}
     if sp:
         kw["sparse_ffn"] = dataclasses.replace(base.sparse_ffn, **sp)
-    return base.replace(**kw)
+    extra = s.get("program", {})
+    unknown = sorted(set(extra) - {f.name for f in dataclasses.fields(base)})
+    if unknown:
+        raise ValueError(f"serve.program names no ModelConfig field: "
+                         f"{', '.join(unknown)}")
+    mapped = sorted(set(extra) & set(kw))
+    if mapped:
+        raise ValueError(f"serve.program sets what the file's keys set: "
+                         f"{', '.join(mapped)}")
+    return base.replace(**kw, **extra)
 
 
-def _leaves(pc) -> dict:
-    """path -> (shape, per-row std on axis -2 or one std, zero?)."""
+def default_leaves(pc) -> dict:
+    """path -> (shape, std): one std, a tuple of per-row stds on axis
+    -2, or 0.0 for zeros."""
     D, L, Vp = pc.d_model, pc.num_layers, pc.vocab_padded
     H, KV, dh = pc.num_heads, pc.num_kv_heads, pc.d_head
     s = 1.0 / math.sqrt(D)
@@ -94,10 +117,17 @@ def _leaves(pc) -> dict:
     return out
 
 
-def make_weights(pc, seed: int, check_against_program: bool = True):
-    """Every leaf from the seed, in the served dtype, on the device."""
+def make_weights(pc, seed: int, check_against_program: bool = True,
+                 reference=None, cfg: dict = None):
+    """Every leaf from the seed, in the served dtype, on the device:
+    the tree `reference.leaves(pc, cfg)` declares, or the default one.
+    Paths are drawn in sorted order, each from its own fold of the
+    key, so a leaf's values depend on the set of paths alone."""
     dtype = jnp.dtype(pc.param_dtype)
-    leaves = _leaves(pc)
+    if hasattr(reference, "leaves"):
+        leaves = reference.leaves(pc, cfg)
+    else:
+        leaves = default_leaves(pc)
     paths = sorted(leaves)
 
     def gen(key):
@@ -126,12 +156,30 @@ def make_weights(pc, seed: int, check_against_program: bool = True):
         from repro.serving.families import serving_family
         want = jax.eval_shape(serving_family(pc).make_model(pc).init,
                               jax.random.key(0))
-        got = jax.tree.map(lambda a: (a.shape, a.dtype), tree)
-        exp = jax.tree.map(lambda a: (a.shape, a.dtype), want)
-        if got != exp:
-            raise ValueError(f"benchmark weights do not match the "
-                             f"program's parameter tree:\n{got}\n{exp}")
+        diff = tree_diff(tree, want)
+        if diff:
+            raise ValueError("benchmark weights do not match the "
+                             "program's parameter tree:\n" + diff)
     return tree
+
+
+def _shapes(tree) -> dict:
+    return {"/".join(str(getattr(k, "key", k)) for k in path):
+            f"{tuple(a.shape)} {a.dtype}"
+            for path, a in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def tree_diff(got, want) -> str:
+    """The paths only one of two trees has, and those whose shape or
+    dtype differ; empty where they agree."""
+    g, w = _shapes(got), _shapes(want)
+    lines = [f"  only the benchmark's: {p} {g[p]}"
+             for p in sorted(g.keys() - w.keys())]
+    lines += [f"  only the program's: {p} {w[p]}"
+              for p in sorted(w.keys() - g.keys())]
+    lines += [f"  differs: {p} benchmark {g[p]}, program {w[p]}"
+              for p in sorted(g.keys() & w.keys()) if g[p] != w[p]]
+    return "\n".join(lines)
 
 
 def nbytes(tree) -> int:

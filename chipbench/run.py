@@ -112,11 +112,15 @@ def check(readings: dict, limits: dict):
 
 
 class Ctx:
-    """What a per-layer metric reader may read."""
+    """What a per-layer metric reader may read. `flops_token(cfg,
+    ctx_len)` counts a decode token's model FLOPs: the cell reference's
+    `model_flops_token` where it defines one, else the yardstick's."""
 
     def __init__(self, cfg, run, plans, buckets, peak, chips, trace=None,
-                 trace_obj=None, traced=None):
+                 trace_obj=None, traced=None, flops_token=None):
+        from chipbench import counts
         self.cfg, self.run, self.plans, self.buckets = cfg, run, plans, buckets
+        self._flops_token = flops_token or counts.model_flops_token
         self.peak, self.chips = peak, chips
         self.trace, self.trace_obj = trace, trace_obj
         self.traced = traced                      # (t0_ns, t1_ns, steps)
@@ -128,6 +132,10 @@ class Ctx:
 
     def note(self, msg):
         self.notes.append(msg)
+
+    def model_flops_token(self, ctx_len: int) -> int:
+        """Model FLOPs to decode one token attending over `ctx_len`."""
+        return self._flops_token(self.cfg, ctx_len)
 
     def bucket(self, step) -> int:
         n = len(step.tokens)
@@ -222,8 +230,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peak: dict,
     stamps = {"imports": time.perf_counter() - t_process}
 
     t = time.perf_counter()
-    pc = model.program_config(cfg)
-    weights = model.make_weights(pc, seed)
+    ref = cell.reference()
+    pc = model.program_config(cfg, ref)
+    weights = model.make_weights(pc, seed, reference=ref, cfg=cfg)
     jax.block_until_ready(weights)
     stamps["weights"] = time.perf_counter() - t
     log(f"weights: {model.nbytes(weights) / 2**30:.3f} GiB in "
@@ -303,8 +312,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peak: dict,
                           ctx_budget=int(mix["engine"]["ctx_budget"]))
     t = time.perf_counter()
     c4 = watch.snap()
-    readings = cell.reference().readings(cfg, weights, served,
-                                         control=control)
+    readings = ref.readings(cfg, weights, served, control=control)
     c5 = watch.snap()
     log(f"reference: {time.perf_counter() - t:.2f} s over "
         f"{readings.get('requests')} requests, {readings.get('tokens')} "
@@ -333,7 +341,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peak: dict,
             min(s for _, s, _ in tr.spans), max(e for _, _, e in tr.spans))
         red = trace_mod.reduce(tr, t0, t1)
         ctx = Ctx(cfg, run, plans, served.buckets, peak, cell.chips,
-                  trace=red, trace_obj=tr, traced=(t0, t1, traced))
+                  trace=red, trace_obj=tr, traced=(t0, t1, traced),
+                  flops_token=getattr(ref, "model_flops_token", None))
         result["metrics"] = {}
         for m in cell.per_layer:
             v = spec_mod.metric_reader(m["name"])(ctx)

@@ -7,11 +7,26 @@ configuration, mix or metric by name, so a later cell, configuration
 or metric is added with files and `BENCHMARK.json` entries alone:
 
     chipbench/configs/<config>.json    sizes, serving settings, and the
-                                       name of its plain reference
-    chipbench/references/<ref>.py      that reference (`readings`)
+                                       name of its plain reference; its
+                                       `serve.program` sets ModelConfig
+                                       fields no key maps
+    chipbench/references/<ref>.py      that reference
     chipbench/traffic/<traffic>.json   parameters of the generator
     chipbench/limits/<workload>.json   the limits `correct` holds
     chipbench/metrics/<metric>.py      `read(ctx)` -> number or None
+
+What a reference module defines:
+
+    readings(cfg, weights, served, control)   required: the numbers the
+                                       limits hold, from what was served
+    leaves(pc, cfg)                    optional: the weight tree to draw,
+                                       {path: (shape, std)} as
+                                       `model.default_leaves` gives it;
+                                       required where the file states
+                                       leading dense layers
+    model_flops_token(cfg, ctx)        optional: a decode token's model
+                                       FLOPs, in place of
+                                       `counts.model_flops_token`
 """
 from __future__ import annotations
 
@@ -49,10 +64,12 @@ class Cell:
     limits: dict
     end_to_end: list          # metric entries this cell reports, trace 0
     per_layer: list           # metric entries this cell reports, trace 1
+    here: Path = HERE         # the benchmark's directory the files are in
 
     def reference(self):
-        return load_module(HERE / "references" / f"{self.config['reference']}.py",
-                           f"chipbench_ref_{self.config['reference']}")
+        name = self.config["reference"]
+        return load_module(self.here / "references" / f"{name}.py",
+                           f"chipbench_ref_{name}")
 
 
 def _applies(metric: dict, workload: str) -> bool:
@@ -79,7 +96,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
         config=load_json(root / cfg_entry["file"]),
         traffic=load_json(here / "traffic" / f"{w['traffic']}.json"),
         limits=load_json(here / "limits" / f"{workload}.json"),
-        end_to_end=e2e, per_layer=per_layer)
+        end_to_end=e2e, per_layer=per_layer, here=here)
 
 
 def metric_reader(name: str):

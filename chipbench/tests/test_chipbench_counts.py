@@ -32,6 +32,42 @@ def test_moe_flops_per_token_hand_sum():
         10 * (attn + ffn) + 2 * 2048 * 102400
 
 
+def test_moe_with_a_leading_dense_layer_flops_hand_sum():
+    # DeepSeekMoE-16B as published: layer 0 a dense FFN of 10944, the
+    # other nine router + 2 shared + 6 routed experts of 1408
+    cfg = dict(MOE, first_k_dense_replace=1, intermediate_size=10944)
+    ctx = 7
+    attn = 4 * 2 * 2048 * 2048 + 4 * 16 * 128 * ctx
+    dense = 3 * 2 * 2048 * 10944
+    moe = 2 * 2048 * 64 + (2 + 6) * 3 * 2 * 2048 * 1408
+    assert counts.model_flops_token(cfg, ctx) == \
+        10 * attn + dense + 9 * moe + 2 * 2048 * 102400
+
+
+def test_a_references_flop_count_replaces_the_yardstick():
+    """decode_mfu counts through the cell's context: the reference's
+    model_flops_token where it defines one."""
+    from types import SimpleNamespace as NS
+
+    from chipbench.run import Ctx
+    steps = [NS(window=True, index=i, tokens={0: 1, 1: 2}, finished=[])
+             for i in range(3)]
+    run = NS(steps=steps, window=(0.0, 2.0),
+             requests={0: NS(prompt=[0] * 5, first_step=0),
+                       1: NS(prompt=[0] * 9, first_step=1)})
+    peak = counts.peaks("TPU v5 lite")
+    mfu = spec.metric_reader("decode_mfu")
+    own = Ctx(SMOLLM, run, {}, (8,), peak, 1,
+              flops_token=lambda cfg, n: 10**9 * n)
+    # positions + 1: request 0 at 6, 7, 8; request 1 at 9, 10, 11
+    assert mfu(own) == pytest.approx(
+        100 * 10**9 * (6 + 7 + 8 + 9 + 10 + 11) / 2.0 / 197e12)
+    plain = Ctx(SMOLLM, run, {}, (8,), peak, 1)
+    total = sum(counts.model_flops_token(SMOLLM, n)
+                for n in (6, 7, 8, 9, 10, 11))
+    assert mfu(plain) == pytest.approx(100 * total / 2.0 / 197e12)
+
+
 def test_cold_kernel_bucket4_plan_hand_sum():
     # smollm bucket-4 plan: n_hot 64, kc 1 cluster of 64 rows, rank 64
     c = counts.cold_kernel_call(rows=4, d_model=576, rank=64,
